@@ -24,6 +24,20 @@ from repro.core import (
 from repro.data.tpch import generate_tpch
 
 
+def table4_query():
+    """The single-dictionary-key group-by the relagg kernel serves
+    (``l_returnflag``: 3 groups), over the ``discount_price`` UDF."""
+    return (
+        scan("lineitem")
+        .filter(col("l_quantity") > 10)
+        .group_by(
+            "l_returnflag",
+            rev=sum_(udf("discount_price", col("l_extendedprice"),
+                         col("l_discount"))),
+        )
+    )
+
+
 def run(quick: bool = False, sf: float = 0.02):
     db = Session()
     generate_tpch(db, sf=sf)
@@ -33,15 +47,7 @@ def run(quick: bool = False, sf: float = 0.02):
     u.return_(param("price") * (1.0 - param("disc")))
     db.create_function(u.build())
 
-    q = (
-        scan("lineitem")
-        .filter(col("l_quantity") > 10)
-        .group_by(
-            "l_returnflag",
-            rev=sum_(udf("discount_price", col("l_extendedprice"),
-                         col("l_discount"))),
-        )
-    )
+    q = table4_query()
 
     fn_sort = db.prepare(q, FROID)
     t_sort = time_run(fn_sort)

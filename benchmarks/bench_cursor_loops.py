@@ -55,23 +55,24 @@ INTERP_N_QUICK = 4
 SWEEP = (32, 1024)
 
 
-def _setup(quick: bool) -> Session:
-    m = M_FACTS_QUICK if quick else M_FACTS
-    db = Session()
-    rng = np.random.default_rng(0)
+def create_tables(db, m: int = M_FACTS, facts: str = "facts",
+                  keys: str = "keys", fn: str = "floop", seed: int = 0) -> None:
+    """The cursor's fact table (``m`` rows), the N_KEYS outer rows and the
+    cursor-loop UDF ``fn`` over ``facts``, under the given names."""
+    rng = np.random.default_rng(seed)
     db.create_table(
-        "facts",
+        facts,
         fk=rng.integers(0, 8, m),
         val=np.round(rng.uniform(-10, 10, m), 2).astype(np.float32),
         qty=rng.integers(0, 9, m),
     )
-    db.create_table("keys", k=np.arange(N_KEYS))
+    db.create_table(keys, k=np.arange(N_KEYS))
     # order-dependent running fold with an early-exit BREAK: scan-kind
     # lowering (a predicated lax.scan), the rewrite's hardest shape
-    u = UdfBuilder("floop", [("x", "float32")], "float32")
+    u = UdfBuilder(fn, [("x", "float32")], "float32")
     u.declare("t", "float32", lit(0.0))
     u.declare("v", "float32", None)
-    with u.cursor_loop({"v": "val"}, scan("facts"),
+    with u.cursor_loop({"v": "val"}, scan(facts),
                        where=col("fk") <= param("x")):
         u.set("t", var("t") * 0.5 + var("v"))
         with u.if_(var("t") > lit(75.0)):
@@ -81,14 +82,20 @@ def _setup(quick: bool) -> Session:
     loop = next(s for s in f.body if isinstance(s, CursorLoop))
     assert classify(loop).kind == "scan"
     db.create_function(f)
+
+
+def _setup(quick: bool) -> Session:
+    db = Session()
+    create_tables(db, M_FACTS_QUICK if quick else M_FACTS)
     return db
 
 
-def _q():
+def query(keys: str = "keys", fn: str = "floop"):
+    """Per outer key below @cut: the cursor loop's fold at k + @shift."""
     return (
-        scan("keys")
+        scan(keys)
         .filter(col("k") < param("cut"))
-        .compute(out=udf("floop", col("k") * 1.0 + param("shift")))
+        .compute(out=udf(fn, col("k") * 1.0 + param("shift")))
         .project("k", "out")
     )
 
@@ -115,8 +122,8 @@ def run(quick: bool = False):
     db = _setup(quick)
     interp_n = INTERP_N_QUICK if quick else INTERP_N
     cpus = os.cpu_count() or 1
-    s_interp = db.prepare(_q(), INTERPRETED)
-    s_froid = db.prepare(_q(), FROID)
+    s_interp = db.prepare(query(), INTERPRETED)
+    s_froid = db.prepare(query(), FROID)
 
     # parity first (also pays both arms' warm-up): the rewritten LoopScan
     # plan must reproduce the per-row interpreted loop bit-for-bit on

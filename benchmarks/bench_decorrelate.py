@@ -73,25 +73,33 @@ def per_row_optimizer():
         O.optimize = orig
 
 
-def _setup(n_keys: int) -> Session:
-    db = Session()
-    rng = np.random.default_rng(0)
+def create_tables(db, n_keys: int, facts: str = "facts",
+                  keys: str = "keys", seed: int = 0) -> None:
+    """The fact table (M_FACTS rows over DOMAIN keys) and the ``n_keys``
+    outer rows, under the given table names."""
+    rng = np.random.default_rng(seed)
     db.create_table(
-        "facts",
+        facts,
         fk=rng.integers(0, DOMAIN, M_FACTS),
         val=rng.normal(size=M_FACTS).astype(np.float32),
         qty=rng.integers(0, 9, M_FACTS),
     )
-    db.create_table("keys", k=np.arange(n_keys) % DOMAIN)
+    db.create_table(keys, k=np.arange(n_keys) % DOMAIN)
+
+
+def _setup(n_keys: int) -> Session:
+    db = Session()
+    create_tables(db, n_keys)
     return db
 
 
-def _q():
-    body = (scan("facts")
+def query(facts: str = "facts", keys: str = "keys"):
+    """Per outer key: SUM(val) of its facts with qty >= @minq."""
+    body = (scan(facts)
             .filter((col("fk") == S.Outer("k"))
                     & (col("qty") >= param("minq")))
             .agg(total=sum_(col("val"))))
-    return (scan("keys").compute(total=scalar_subquery(body, "total"))
+    return (scan(keys).compute(total=scalar_subquery(body, "total"))
             .project("k", "total"))
 
 
@@ -149,7 +157,7 @@ def run(quick: bool = False):
 
     for n in SWEEP:
         db = _setup(n)
-        q = _q()
+        q = query()
         dec_stmt = db.prepare(q, FROID)
         assert not _has_corr(dec_stmt.plan), "rewrite did not fire"
         builds = sum(1 for nd in R.walk_plan(dec_stmt.plan)
@@ -157,7 +165,7 @@ def run(quick: bool = False):
 
         with per_row_optimizer():
             db_row = _setup(n)
-            row_stmt = db_row.prepare(_q(), FROID)
+            row_stmt = db_row.prepare(query(), FROID)
         assert _has_corr(row_stmt.plan), "per-row arm was rewritten"
 
         node = q.node

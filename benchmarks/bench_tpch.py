@@ -17,19 +17,18 @@ SF = 0.02  # 120k lineitems (CPU-scale)
 
 
 def _results_match(db, qa, qb) -> bool:
+    """Whether two queries' results agree; a failure while comparing
+    raises instead of reading as a mismatch."""
     ra = db.execute(qa, FROID).table
     rb = db.execute(qb, FROID).table
-    try:
-        for name in ra.names():
-            if name not in rb.columns:
-                continue
-            a = np.asarray(ra.columns[name].data, np.float64)
-            b = np.asarray(rb.columns[name].data, np.float64)
-            if a.shape != b.shape or not np.allclose(a, b, rtol=2e-3, atol=1e-2):
-                return False
-        return True
-    except Exception:
-        return False
+    for name in ra.names():
+        if name not in rb.columns:
+            continue
+        a = np.asarray(ra.columns[name].data, np.float64)
+        b = np.asarray(rb.columns[name].data, np.float64)
+        if a.shape != b.shape or not np.allclose(a, b, rtol=2e-3, atol=1e-2):
+            return False
+    return True
 
 
 def run(quick: bool = False, sf: float = SF):

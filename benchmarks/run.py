@@ -53,6 +53,10 @@ def main() -> None:
                          "('' disables JSON emission)")
     args, _ = ap.parse_known_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         bench_batchmode,
         bench_compile,

@@ -461,6 +461,29 @@ def q19_udf():
     )
 
 
+def q19_orig():
+    def branch(brand, containers, qlo, qhi, shi):
+        return ((col("p_brand") == lit(brand))
+                & in_list(col("p_container"), containers)
+                & between(col("l_quantity"), qlo, qhi)
+                & between(col("p_size"), 1, shi))
+
+    return (
+        scan("lineitem")
+        .join(scan("part"), on=("l_partkey", "p_partkey"))
+        .filter(in_list(col("l_shipmode"), ["AIR", "AIR REG"])
+                & (col("l_shipinstruct") == lit("DELIVER IN PERSON"))
+                & (branch("Brand#12", ["SM CASE", "SM BOX", "SM PACK", "SM PKG"],
+                          1, 11, 5)
+                   | branch("Brand#23",
+                            ["MED BAG", "MED BOX", "MED PKG", "MED PACK"],
+                            10, 20, 10)
+                   | branch("Brand#34", ["LG CASE", "LG BOX", "LG PACK", "LG PKG"],
+                            20, 30, 15)))
+        .agg(revenue=sum_(col("l_extendedprice") * (1.0 - col("l_discount"))))
+    )
+
+
 QUERIES = {
     "Q1": (q1_udf, q1_orig),
     "Q3": (q3_udf, q3_orig),
@@ -468,4 +491,5 @@ QUERIES = {
     "Q6": (q6_udf, q6_orig),
     "Q12": (q12_udf, q12_orig),
     "Q14": (q14_udf, q14_orig),
+    "Q19": (q19_udf, q19_orig),
 }

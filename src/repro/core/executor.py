@@ -246,6 +246,14 @@ class Executor:
     def _exec_join(self, node: R.Join, ctx, memo) -> MaskedTable:
         left = self._exec(node.left, ctx, memo)
         right = self._exec(node.right, ctx, memo)
+        if right.num_rows == 0:
+            # an empty build side matches nothing; one masked-off row keeps
+            # the probe's gathers in range (jnp.take rejects an empty axis)
+            right = MaskedTable(
+                Table({n: Column(jnp.zeros((1,) + c.data.shape[1:], c.data.dtype),
+                                 jnp.zeros((1,), bool), c.dictionary)
+                       for n, c in right.table.columns.items()}),
+                jnp.zeros((1,), bool))
 
         if len(node.on) == 1:
             lcol, rcol = node.on[0]
@@ -612,8 +620,10 @@ class Executor:
     def _try_relagg(self, node: R.GroupAgg, child: MaskedTable, agg_inputs):
         """Fused group-by via the relagg kernel.  Applicable when the key is
         dictionary-encoded (G = vocab size) or a capacity hint bounds a
-        non-negative int key, and all aggs are sum/avg/count/count_star."""
+        non-negative int key, G is within the kernel's VMEM bound
+        (``relagg.MAX_GROUPS``), and all aggs are sum/avg/count/count_star."""
         from repro.kernels.relagg.ops import grouped_aggregate
+        from repro.kernels.relagg.relagg import MAX_GROUPS
 
         key = node.keys[0]
         kc = child.table.columns[key]
@@ -622,6 +632,8 @@ class Executor:
         elif node.capacity is not None and jnp.issubdtype(kc.dtype, jnp.integer):
             G = int(node.capacity)
         else:
+            return None
+        if not 1 <= G <= MAX_GROUPS:
             return None
         if not all(a.fn in ("sum", "avg", "count", "count_star")
                    for a in node.aggs.values()):
@@ -650,6 +662,8 @@ class Executor:
         sums, counts = grouped_aggregate(
             kc.data.astype(jnp.int32), mask, vals, G
         )
+        self._stats["relagg_groupaggs"] = (
+            self._stats.get("relagg_groupaggs", 0) + 1)
         occupied = counts > 0
         out_cols: dict[str, Column] = {
             key: Column(jnp.arange(G, dtype=kc.data.dtype), occupied, kc.dictionary)

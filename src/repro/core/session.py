@@ -574,6 +574,10 @@ class _Executable:
     out_dicts: dict  # column name -> DictEncoding | None (trace-time capture)
     stats: dict  # trace-time logical reads of one execution
     raw: Any = None  # untraced (table_args, param_args) closure (vmap source)
+    #: the AOT ``jax.stages.Compiled`` when one was built or loaded from the
+    #: store (its ``as_text()`` is the optimized HLO); None on the lazily
+    #: jitted path
+    compiled: Any = None
 
 
 @dataclasses.dataclass
@@ -947,10 +951,11 @@ class Session:
 
     def _persist_save(self, store, key: tuple, compiled, *, out_dicts,
                       stats, extra: dict | None = None) -> bool:
-        """Write-behind save of a freshly-compiled executable; failures are
-        counted, never raised (persistence is an optimization, not a
-        correctness dependency)."""
+        """Write-behind save of a freshly-compiled executable; serialization
+        and store failures are counted and warned, never raised
+        (persistence is an optimization, not a correctness dependency)."""
         from repro.persist import codec
+        from repro.persist.store import PlanCacheWarning
 
         try:
             blob = codec.pack_compiled(compiled)
@@ -961,8 +966,11 @@ class Session:
             if extra:
                 meta.update(extra)
             store.put(key, meta, blob)
-        except Exception:
+        except Exception as e:  # native serialize / disk: anything can surface
             self._persist_extra["save_errors"] += 1
+            warnings.warn(
+                f"persistent plan save failed ({type(e).__name__}: {e}); "
+                f"serving from memory", PlanCacheWarning, stacklevel=3)
             return False
         self._persist_extra["saves"] += 1
         return True
@@ -1110,18 +1118,16 @@ class Session:
                                  or {})
                 trace_stats.update(pmeta.get("stats") or {})
             else:
-                try:
-                    pargs0 = {}
-                    for pname, x in (params or {}).items():
-                        v = _param_value(x)
-                        pargs0[pname] = (v.data, v.validity())
-                    target = jax.jit(raw).lower(
-                        self._catalog_args(), pargs0).compile()
-                    self._persist_save(store, pkey, target,
-                                       out_dicts=out_dicts, stats=trace_stats)
-                except Exception:
-                    self._persist_extra["save_errors"] += 1
-                    target = None
+                # a compile failure raises here; only the save degrades
+                pargs0 = {}
+                for pname, x in (params or {}).items():
+                    v = _param_value(x)
+                    pargs0[pname] = (v.data, v.validity())
+                target = jax.jit(raw).lower(
+                    self._catalog_args(), pargs0).compile()
+                self._persist_save(store, pkey, target,
+                                   out_dicts=out_dicts, stats=trace_stats)
+        compiled = target
         if target is None:
             target = jax.jit(raw)
 
@@ -1133,7 +1139,8 @@ class Session:
                 pargs[pname] = (v.data, v.validity())
             return target(self._catalog_args(catalog_token), pargs)
 
-        entry = _Executable(fn, plan, out_dicts, trace_stats, raw=raw)
+        entry = _Executable(fn, plan, out_dicts, trace_stats, raw=raw,
+                            compiled=compiled)
         self._execs[key] = entry
         return entry, False, plan_hit
 
@@ -1174,17 +1181,12 @@ class Session:
             if loaded is not None:
                 target, _pmeta = loaded
             else:
-                try:
-                    target = jax.jit(
-                        jax.vmap(base.raw, in_axes=(None, 0))).lower(
-                        self._catalog_args(),
-                        _batched_avals(params0, bucket)).compile()
-                    self._persist_save(store, pkey, target,
-                                       out_dicts=base.out_dicts,
-                                       stats=base.stats)
-                except Exception:
-                    self._persist_extra["save_errors"] += 1
-                    target = None
+                target = jax.jit(
+                    jax.vmap(base.raw, in_axes=(None, 0))).lower(
+                    self._catalog_args(),
+                    _batched_avals(params0, bucket)).compile()
+                self._persist_save(store, pkey, target,
+                                   out_dicts=base.out_dicts, stats=base.stats)
         if target is None:
             target = jax.jit(jax.vmap(base.raw, in_axes=(None, 0)))
 
@@ -1253,9 +1255,9 @@ class Session:
         # input shardings are explicit (a serialized executable is
         # specialized to placements, not just avals), so the AOT path jits
         # with in_shardings = (replicated catalog, sharded param axis) —
-        # exactly the placements fn below commits its inputs to.  Any
-        # failure (lowering, serialization, a store reject) falls back to
-        # the inference-jitted path.
+        # exactly the placements fn below commits its inputs to.  A store
+        # failure (serialization, a store reject) only skips the save; a
+        # compile failure raises.
         from repro.dist.sharding import replicated_sharding
 
         store = self._persist_store(policy)
@@ -1267,19 +1269,14 @@ class Session:
             if loaded is not None:
                 target, _pmeta = loaded
             else:
-                try:
-                    target = jax.jit(
-                        jax.vmap(base.raw, in_axes=(None, 0)),
-                        in_shardings=(replicated_sharding(mesh),
-                                      parg_sharding)).lower(
-                        self._catalog_args(),
-                        _batched_avals(params0, bucket)).compile()
-                    self._persist_save(store, pkey, target,
-                                       out_dicts=base.out_dicts,
-                                       stats=base.stats)
-                except Exception:
-                    self._persist_extra["save_errors"] += 1
-                    target = None
+                target = jax.jit(
+                    jax.vmap(base.raw, in_axes=(None, 0)),
+                    in_shardings=(replicated_sharding(mesh),
+                                  parg_sharding)).lower(
+                    self._catalog_args(),
+                    _batched_avals(params0, bucket)).compile()
+                self._persist_save(store, pkey, target,
+                                   out_dicts=base.out_dicts, stats=base.stats)
         if target is None:
             # one leading-axis spec serves every stacked-param leaf
             # (trailing dims replicate); catalog args broadcast whole
@@ -1399,16 +1396,13 @@ class Session:
             member_tmaps, slot_names)
         jitted = jax.jit(raw)
         if persistable:
-            try:
-                compiled = jitted.lower(self._catalog_args(),
-                                        *example_args).compile()
-                self._persist_save(
-                    store, pkey, compiled, out_dicts=None, stats=trace_stats,
-                    extra={"out_dicts_list":
-                           [_codec.encode_dicts(d) for d in out_dicts]})
-                jitted = compiled  # single compile: reuse the AOT artifact
-            except Exception:
-                self._persist_extra["save_errors"] += 1
+            compiled = jitted.lower(self._catalog_args(),
+                                    *example_args).compile()
+            self._persist_save(
+                store, pkey, compiled, out_dicts=None, stats=trace_stats,
+                extra={"out_dicts_list":
+                       [_codec.encode_dicts(d) for d in out_dicts]})
+            jitted = compiled  # single compile: reuse the AOT artifact
         if shard:
             from repro.dist.sharding import batch_sharding, replicated_sharding
 

@@ -56,6 +56,20 @@ def data_axis_size(mesh) -> int:
     return _axis_product(mesh, _data_axes(mesh))
 
 
+def _auto_axes(mesh):
+    """``mesh`` with every axis ``Auto``.  ``jax.make_mesh`` types axes
+    ``Explicit`` by default, which makes every traced array carry its
+    sharding in its type; the engine's plans (sorts, ``searchsorted``,
+    gathers under ``vmap``) have no explicit-sharding rules, so the engine
+    places its batches on Auto axes and lets the compiler propagate."""
+    from jax.sharding import AxisType, Mesh
+
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def batch_sharding(mesh, dim: int):
     """NamedSharding placing a leading ``dim``-sized batch axis over the
     data axes, or None when divisibility gating rejects it.  Used as a jit
@@ -64,13 +78,13 @@ def batch_sharding(mesh, dim: int):
     entry = pick_data_axes(mesh, dim)
     if entry is None:
         return None
-    return NamedSharding(mesh, PartitionSpec(entry))
+    return NamedSharding(_auto_axes(mesh), PartitionSpec(entry))
 
 
 def replicated_sharding(mesh):
     """NamedSharding replicating a value on every device of ``mesh`` —
     how catalog tables broadcast under sharded batch execution."""
-    return NamedSharding(mesh, PartitionSpec())
+    return NamedSharding(_auto_axes(mesh), PartitionSpec())
 
 
 def _fsdp_entry(mesh, shape, taken: int | None):

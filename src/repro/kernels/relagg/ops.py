@@ -11,12 +11,13 @@ def _on_tpu() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "block_rows", "interpret"))
-def grouped_aggregate(gid, mask, vals, num_groups, block_rows=1024, interpret=None):
+def grouped_aggregate(gid, mask, vals, num_groups, block_rows=None, interpret=None):
     """Fused filter+group+aggregate.  Returns (sums (G, n_aggs), counts (G,)).
 
     ``interpret=None`` auto-selects: compiled on TPU, interpret elsewhere
-    (this container is CPU-only; interpret mode executes the kernel body in
-    Python for correctness validation)."""
+    (interpret mode executes the kernel body op by op on the CPU, for
+    correctness tests).  ``block_rows=None`` sizes the row tile from the
+    group count (``relagg.pick_block_rows``)."""
     if interpret is None:
         interpret = not _on_tpu()
     return relagg_pallas(

@@ -139,8 +139,6 @@ class Roofline:
 
 def roofline_from_compiled(compiled, chips: int) -> tuple[Roofline, CollectiveStats]:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # pre-0.5 jax: one dict per device
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     colls = parse_collectives(compiled.as_text())

@@ -8,9 +8,13 @@ than a cold trace for the statements in this repo.  The flip side is that the
 payload is a native artifact, so the store's runtime stamp (jax/jaxlib,
 backend, device count) gates every load; a mismatch degrades to recompile.
 
-The blob is a pickle of ``(payload, in_tree, out_tree)`` exactly as returned
-by ``serialize_executable.serialize`` (the two ``PyTreeDef``s are not part of
-the payload and pickle round-trips them faithfully).  Host-side row metadata
+The blob is a pickle of ``(payload, in_tree, out_tree, device_ids)``: the
+first three exactly as returned by ``serialize_executable.serialize`` (the
+two ``PyTreeDef``s are not part of the payload and pickle round-trips them
+faithfully), the last the ids of the devices the executable ran on.  Loading
+pins execution to those devices: left to its default, the loader assigns
+*every* local device, so a one-device program saved in a multi-device
+process would expect one argument shard per device.  Host-side row metadata
 (dictionary-encoded output vocabularies, trace-time stats) travels in the
 JSON entry header via :func:`encode_dicts`/:func:`decode_dicts` so a warm
 load can rebuild ``QueryResult`` decoding state without tracing.
@@ -20,6 +24,7 @@ from __future__ import annotations
 import pickle
 from typing import Any, Callable, Mapping
 
+import jax
 from jax.experimental import serialize_executable as _se
 
 from repro.tables.table import DictEncoding
@@ -28,13 +33,21 @@ from repro.tables.table import DictEncoding
 def pack_compiled(compiled: Any) -> bytes:
     """Serialize a ``jax.stages.Compiled`` to an opaque blob."""
     payload, in_tree, out_tree = _se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree), protocol=pickle.HIGHEST_PROTOCOL)
+    shardings = jax.tree.leaves((compiled.input_shardings,
+                                 compiled.output_shardings))
+    device_ids = sorted({d.id for s in shardings for d in s.device_set})
+    return pickle.dumps((payload, in_tree, out_tree, device_ids),
+                        protocol=pickle.HIGHEST_PROTOCOL)
 
 
 def load_compiled(blob: bytes) -> Callable:
-    """Rehydrate a callable executable from :func:`pack_compiled` bytes."""
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
+    """Rehydrate a callable executable from :func:`pack_compiled` bytes.
+    Raises ``KeyError`` when a device it ran on is absent here."""
+    payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 def encode_dicts(out_dicts: Mapping[str, DictEncoding | None] | None) -> dict | None:

@@ -28,7 +28,7 @@ from repro.persist.keys import assert_stable_key, key_digest
 
 #: Bump on any incompatible change to entry payloads or key layout; old
 #: entries are then rejected (recompile) instead of misread.
-PERSIST_SCHEMA_VERSION = 1
+PERSIST_SCHEMA_VERSION = 2
 
 _MAGIC = b"RPRPLAN\x01"
 _LEN = struct.Struct("<I")

@@ -54,6 +54,7 @@ def test_decorr_oracle_empty_inner(agg):
     NULL (COUNT goes 0), EXISTS goes false, semi joins empty out."""
     check_decorrelation_oracle("agg", "direct", agg, seed=2, n_rows=0)
     check_decorrelation_oracle("exists", "direct", agg, seed=2, n_rows=0)
+    check_decorrelation_oracle("semi", "direct", agg, seed=2, n_rows=0)
 
 
 def test_decorr_oracle_missing_groups_null_semantics():

@@ -8,6 +8,7 @@ from repro.core import Database, avg_, col, count_, lit, max_, min_, scan, sum_
 from repro.core import relalg as R
 from repro.core import scalar as S
 from repro.core.executor import Executor
+from repro.kernels.relagg.relagg import MAX_GROUPS
 from repro.tables.table import Table, civil_from_days, date_add, date_part, days_from_civil
 
 
@@ -188,12 +189,16 @@ def test_groupagg_capacity_overflow_guard(rng):
     assert r.num_rows == len(np.unique(np.asarray(db.catalog["t"].columns["k"].data)))
 
 
-def test_relagg_batchmode_matches_sort_path(rng):
+@pytest.mark.parametrize("n_keys", [3, MAX_GROUPS + 5])
+def test_relagg_batchmode_matches_sort_path(rng, n_keys):
     """GroupAgg via the fused Pallas relagg kernel (batch mode, §8.2.6)
-    equals the sort-based path on a dictionary key."""
+    equals the sort-based path on a dictionary key; a vocabulary past the
+    kernel's group bound declines the kernel and still matches."""
     db = Database()
-    n = 500
-    flags = np.array(["A", "B", "C"])[rng.integers(0, 3, n)]
+    n = n_keys + 500
+    # every key occurs, so the vocabulary size is exactly n_keys
+    flags = np.array([f"F{i}" for i in range(n_keys)])[
+        np.concatenate([np.arange(n_keys), rng.integers(0, n_keys, 500)])]
     db.create_table(
         "li",
         flag=flags,
