@@ -15,6 +15,11 @@ Design (TPU adaptation of the paper's set-oriented plans, DESIGN.md §2):
   ``relagg`` kernel for the single-pass filter+project+aggregate hot path.
 * **CSE for free** — node results are memoized per execution, which is the
   relational version of common-subexpression elimination (paper §6).
+* **Named operators** — each node runs under ``jax.named_scope`` of its
+  operator family (:data:`SCOPES`), so the device ops it lowers to carry
+  ``froid.<family>`` in their op-name metadata; a child's scope nests in
+  its parent's, and the innermost ``froid.*`` component names the family
+  an op belongs to.  The scopes cost nothing at run time.
 """
 from __future__ import annotations
 
@@ -119,6 +124,17 @@ def _union_dense_rank(left: "MaskedTable", right: "MaskedTable", on):
     return lkeys, rkeys
 
 
+#: the operator family each plan node's device ops are scoped under
+SCOPES = {
+    R.Scan: "froid.scan", R.ConstantScan: "froid.scan",
+    R.Filter: "froid.filter",
+    R.Project: "froid.project", R.Compute: "froid.project",
+    R.Join: "froid.join", R.Apply: "froid.apply",
+    R.GroupAgg: "froid.groupagg", R.Sort: "froid.sort",
+    R.LoopScan: "froid.loopscan",
+}
+
+
 class Executor:
     """Evaluates relational plans over a catalog of named Tables."""
 
@@ -161,7 +177,8 @@ class Executor:
         key = node.node_id
         if key in memo:
             return memo[key]
-        out = self._exec_node(node, ctx, memo)
+        with jax.named_scope(SCOPES.get(type(node), "froid")):
+            out = self._exec_node(node, ctx, memo)
         memo[key] = out
         return out
 
@@ -864,7 +881,8 @@ class Executor:
                 f"UDF {expr.name!r} not inlined and no iterative evaluator "
                 "attached (enable froid, or run via the interpreter)"
             )
-        return self.udf_column_evaluator(expr, env, ctx)
+        with jax.named_scope("froid.udf"):
+            return self.udf_column_evaluator(expr, env, ctx)
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import itertools
+import threading
 import time
 import warnings
 from collections import OrderedDict, deque
@@ -43,6 +44,7 @@ from repro.core.interpreter import Interpreter
 from repro.core.ir import UdfDef
 from repro.core.policy import FROID, ExecutionPolicy, resolve_policy
 from repro.tables.table import Column, DictEncoding, Table
+from repro.telemetry import CATALOG_EVENT, span
 
 
 # ---------------------------------------------------------------------------
@@ -567,9 +569,22 @@ def _plan_template_groups(merged, members, params_by_member):
 # ---------------------------------------------------------------------------
 
 
+class _Program:
+    """A compiled program split where the host's work meets the device's:
+    ``args(...)`` packs the call's arguments (parameters, catalog arrays,
+    their placement) and ``target`` runs them.  ``source`` says where
+    ``target`` came from: ``"aot"`` (lowered and compiled at the cache
+    miss), ``"store"`` (loaded from the ``PlanStore``) or ``"jit"``
+    (compiled by its first call)."""
+
+    #: whether a lazily jitted ``target`` has run, and so compiled
+    ran = False
+
+
 @dataclasses.dataclass
-class _Executable:
-    fn: Any  # () kwargs-free jitted callable wrapper, see Session._executable
+class _Executable(_Program):
+    args: Any  # (param_values, catalog_token) -> (table_args, param_args)
+    target: Any  # the program over those arguments
     plan: R.RelNode
     out_dicts: dict  # column name -> DictEncoding | None (trace-time capture)
     stats: dict  # trace-time logical reads of one execution
@@ -578,25 +593,41 @@ class _Executable:
     #: store (its ``as_text()`` is the optimized HLO); None on the lazily
     #: jitted path
     compiled: Any = None
+    source: str = "jit"
 
 
 @dataclasses.dataclass
-class _BatchedExecutable:
-    fn: Any  # (batched_pargs, catalog_token) -> (mask (B,n), cols)
+class _BatchedExecutable(_Program):
+    args: Any  # (batched_pargs, catalog_token) -> the program's arguments
+    target: Any  # -> (mask (B,n), cols)
     plan: R.RelNode
     out_dicts: dict  # shared with the unbatched executable's capture
     stats: dict
     bucket: int
+    source: str = "jit"
 
 
 @dataclasses.dataclass
-class _ShardedExecutable:
-    fn: Any  # (batched_pargs, catalog_token) -> (mask (B,n), cols), mesh-placed
+class _ShardedExecutable(_Program):
+    args: Any  # (batched_pargs, catalog_token) -> mesh-placed arguments
+    target: Any  # -> (mask (B,n), cols)
     plan: R.RelNode
     out_dicts: dict  # shared with the unbatched executable's capture
     stats: dict
     bucket: int
     devices: int  # data-parallel shard count the bucket spreads over
+    source: str = "jit"
+
+
+def _run(entry: _Program, args: tuple, tier: str):
+    """``entry``'s program on ``args``.  The first call of a lazily jitted
+    program traces and compiles it, under a ``froid.compile`` span."""
+    if entry.ran or entry.source != "jit":
+        return entry.target(*args)
+    with span("froid.compile", tier=tier, source="jit"):
+        out = entry.target(*args)
+    entry.ran = True
+    return out
 
 
 @dataclasses.dataclass
@@ -703,6 +734,19 @@ class Session:
         # dispatched-but-unsynced AsyncResults, oldest first (backpressure)
         self._inflight: deque = deque()
         self.async_stats = {"inflight_waits": 0, "inflight_peak": 0}
+        # host seconds by layer, beside the froid.* spans of the same
+        # intervals: catalog loads; per device program run, its arguments
+        # (executable lookup, parameter packing, catalog arrays), its
+        # dispatch and its sync; per lazy result, its materialization.  A
+        # call that compiles holds the compile in its args_s (AOT or store
+        # load) or dispatch_s (jit); repro.telemetry counts compiles apart
+        self.timing_stats = {
+            "tables": 0, "catalog_s": 0.0,
+            "executions": 0, "args_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0,
+            "materializations": 0, "materialize_s": 0.0,
+        }
+        # lazy results materialize on their consumers' threads
+        self._timing_lock = threading.Lock()
         # resilience seam: a repro.resilience.faults.FaultInjector (or any
         # object with .check(site, statements)) installed by chaos tests;
         # None in production — the seams below are no-ops then
@@ -781,6 +825,24 @@ class Session:
             return {"enabled": False}
         return self.cost_router.snapshot()
 
+    def _timed(self, **amounts) -> None:
+        """Add ``amounts`` to :attr:`timing_stats`."""
+        with self._timing_lock:
+            for k, v in amounts.items():
+                self.timing_stats[k] += v
+
+    def _timed_materialize(self, materialize):
+        """``materialize`` (a lazy result's slicing of its device outputs)
+        under a ``froid.materialize`` span, counted in :attr:`timing_stats`."""
+        def timed(*a):
+            with span("froid.materialize"):
+                t0 = time.perf_counter()
+                out = materialize(*a)
+                t1 = time.perf_counter()
+            self._timed(materializations=1, materialize_s=t1 - t0)
+            return out
+        return timed
+
     def _fault(self, site: str, statements: tuple = ()) -> None:
         """Fault-injection seam: named executor sites call this with the
         statement fingerprints they serve; an installed injector may raise
@@ -792,9 +854,14 @@ class Session:
     # -- DDL ---------------------------------------------------------------
     # name/table are positional-only so columns may be called "name"/"table"
     def create_table(self, name: str, table: Table | None = None, /, **arrays):
-        t = table if table is not None else Table.from_arrays(**arrays)
-        t.compute_stats()  # histograms for the optimizer (§Perf)
-        self.catalog[name] = t
+        with span("froid.catalog", table=name):
+            t0 = time.perf_counter()
+            t = table if table is not None else Table.from_arrays(**arrays)
+            t.compute_stats()  # histograms for the optimizer (§Perf)
+            self.catalog[name] = t
+            dt = time.perf_counter() - t0
+        self._timed(tables=1, catalog_s=dt)
+        jax.monitoring.record_event_duration_secs(CATALOG_EVENT, dt)
         return t
 
     def create_function(self, udf: UdfDef):
@@ -1108,12 +1175,13 @@ class Session:
         from repro.persist import codec as _codec
 
         store = self._persist_store(policy)
-        target = None
+        target, source = None, "jit"
         if store is not None:
             pkey = self._persist_key("exec", query_fp, policy, sig=sig)
-            loaded = self._persist_load(store, pkey)
+            with span("froid.compile", tier="exec", source="store"):
+                loaded = self._persist_load(store, pkey)
             if loaded is not None:
-                target, pmeta = loaded
+                (target, pmeta), source = loaded, "store"
                 out_dicts.update(_codec.decode_dicts(pmeta.get("out_dicts"))
                                  or {})
                 trace_stats.update(pmeta.get("stats") or {})
@@ -1123,24 +1191,26 @@ class Session:
                 for pname, x in (params or {}).items():
                     v = _param_value(x)
                     pargs0[pname] = (v.data, v.validity())
-                target = jax.jit(raw).lower(
-                    self._catalog_args(), pargs0).compile()
+                with span("froid.compile", tier="exec", source="aot"):
+                    target = jax.jit(raw).lower(
+                        self._catalog_args(), pargs0).compile()
+                source = "aot"
                 self._persist_save(store, pkey, target,
                                    out_dicts=out_dicts, stats=trace_stats)
         compiled = target
         if target is None:
             target = jax.jit(raw)
 
-        def fn(param_values: dict | None = None,
-               catalog_token: tuple | None = None):
+        def args(param_values: dict | None = None,
+                 catalog_token: tuple | None = None):
             pargs = {}
             for pname, x in (param_values or {}).items():
                 v = _param_value(x)
                 pargs[pname] = (v.data, v.validity())
-            return target(self._catalog_args(catalog_token), pargs)
+            return self._catalog_args(catalog_token), pargs
 
-        entry = _Executable(fn, plan, out_dicts, trace_stats, raw=raw,
-                            compiled=compiled)
+        entry = _Executable(args, target, plan, out_dicts, trace_stats,
+                            raw=raw, compiled=compiled, source=source)
         self._execs[key] = entry
         return entry, False, plan_hit
 
@@ -1173,28 +1243,31 @@ class Session:
         # AOT compile traces base.raw under vmap — filling the shared
         # capture dicts exactly like the jit path would.
         store = self._persist_store(policy)
-        target = None
+        target, source = None, "jit"
         if store is not None:
             pkey = self._persist_key("batch", query_fp, policy, sig=sig,
                                      bucket=bucket)
-            loaded = self._persist_load(store, pkey)
+            with span("froid.compile", tier="batch", source="store"):
+                loaded = self._persist_load(store, pkey)
             if loaded is not None:
-                target, _pmeta = loaded
+                (target, _pmeta), source = loaded, "store"
             else:
-                target = jax.jit(
-                    jax.vmap(base.raw, in_axes=(None, 0))).lower(
-                    self._catalog_args(),
-                    _batched_avals(params0, bucket)).compile()
+                with span("froid.compile", tier="batch", source="aot"):
+                    target = jax.jit(
+                        jax.vmap(base.raw, in_axes=(None, 0))).lower(
+                        self._catalog_args(),
+                        _batched_avals(params0, bucket)).compile()
+                source = "aot"
                 self._persist_save(store, pkey, target,
                                    out_dicts=base.out_dicts, stats=base.stats)
         if target is None:
             target = jax.jit(jax.vmap(base.raw, in_axes=(None, 0)))
 
-        def fn(batched_pargs: dict, catalog_token: tuple | None = None):
-            return target(self._catalog_args(catalog_token), batched_pargs)
+        def args(batched_pargs: dict, catalog_token: tuple | None = None):
+            return self._catalog_args(catalog_token), batched_pargs
 
-        entry = _BatchedExecutable(fn, base.plan, base.out_dicts, base.stats,
-                                   bucket)
+        entry = _BatchedExecutable(args, target, base.plan, base.out_dicts,
+                                   base.stats, bucket, source=source)
         self._batch_execs[key] = entry
         return entry, False
 
@@ -1261,20 +1334,23 @@ class Session:
         from repro.dist.sharding import replicated_sharding
 
         store = self._persist_store(policy)
-        target = None
+        target, source = None, "jit"
         if store is not None:
             pkey = self._persist_key("shard", query_fp, policy, sig=sig,
                                      bucket=bucket, shard_token=shard_token)
-            loaded = self._persist_load(store, pkey)
+            with span("froid.compile", tier="shard", source="store"):
+                loaded = self._persist_load(store, pkey)
             if loaded is not None:
-                target, _pmeta = loaded
+                (target, _pmeta), source = loaded, "store"
             else:
-                target = jax.jit(
-                    jax.vmap(base.raw, in_axes=(None, 0)),
-                    in_shardings=(replicated_sharding(mesh),
-                                  parg_sharding)).lower(
-                    self._catalog_args(),
-                    _batched_avals(params0, bucket)).compile()
+                with span("froid.compile", tier="shard", source="aot"):
+                    target = jax.jit(
+                        jax.vmap(base.raw, in_axes=(None, 0)),
+                        in_shardings=(replicated_sharding(mesh),
+                                      parg_sharding)).lower(
+                        self._catalog_args(),
+                        _batched_avals(params0, bucket)).compile()
+                source = "aot"
                 self._persist_save(store, pkey, target,
                                    out_dicts=base.out_dicts, stats=base.stats)
         if target is None:
@@ -1282,15 +1358,15 @@ class Session:
             # (trailing dims replicate); catalog args broadcast whole
             target = jax.jit(jax.vmap(base.raw, in_axes=(None, 0)))
 
-        def fn(batched_pargs: dict, catalog_token: tuple | None = None):
+        def args(batched_pargs: dict, catalog_token: tuple | None = None):
             cats = self._catalog_args_replicated(
                 mesh, catalog_token if catalog_token is not None
                 else self._catalog_token(), shard_token)
-            pargs = jax.device_put(batched_pargs, parg_sharding)
-            return target(cats, pargs)
+            return cats, jax.device_put(batched_pargs, parg_sharding)
 
-        entry = _ShardedExecutable(fn, base.plan, base.out_dicts, base.stats,
-                                   bucket, policy.shard_devices())
+        entry = _ShardedExecutable(args, target, base.plan, base.out_dicts,
+                                   base.stats, bucket, policy.shard_devices(),
+                                   source=source)
         self._shard_execs[key] = entry
         return entry, False
 
@@ -1371,7 +1447,8 @@ class Session:
             pkey = self._persist_key(
                 "fused", tuple(m.key for m in members), policy,
                 template=template_token)
-            loaded = self._persist_load(store, pkey)
+            with span("froid.compile", tier="fused", source="store"):
+                loaded = self._persist_load(store, pkey)
             if loaded is not None:
                 compiled, pmeta = loaded
                 out_dicts = [_codec.decode_dicts(d) or {}
@@ -1396,8 +1473,9 @@ class Session:
             member_tmaps, slot_names)
         jitted = jax.jit(raw)
         if persistable:
-            compiled = jitted.lower(self._catalog_args(),
-                                    *example_args).compile()
+            with span("froid.compile", tier="fused", source="aot"):
+                compiled = jitted.lower(self._catalog_args(),
+                                        *example_args).compile()
             self._persist_save(
                 store, pkey, compiled, out_dicts=None, stats=trace_stats,
                 extra={"out_dicts_list":
@@ -1676,11 +1754,12 @@ class Session:
                         )
                     return cell["v"]
 
+                mat = self._timed_materialize(mat_shared)
                 for i in ent["idxs"]:
                     results[i] = QueryResult(
                         None, m.plan, elapsed, dict(stats),
                         policy=ent["stmt"].policy, cache_hit=hit,
-                        materialize=mat_shared,
+                        materialize=mat,
                     )
                 continue
 
@@ -1691,11 +1770,12 @@ class Session:
                 )
                 return MaskedTable(table, mask[row])
 
+            mat = self._timed_materialize(materialize)
             for row, i in enumerate(ent["idxs"]):
                 results[i] = QueryResult(
                     None, m.plan, elapsed, dict(stats),
                     policy=ent["stmt"].policy, cache_hit=hit,
-                    materialize=(lambda row=row, mat=materialize: mat(row)),
+                    materialize=(lambda row=row, mat=mat: mat(row)),
                 )
 
     # -- async backpressure --------------------------------------------------
@@ -1799,7 +1879,7 @@ class PreparedStatement:
         entry, _, _ = self.session._executable(
             self.node, self._query_fp, self.policy, params, env_token
         )
-        return entry.fn(params, env_token[0])
+        return _run(entry, entry.args(params, env_token[0]), "exec")
 
     def execute(self, params: dict | None = None) -> QueryResult:
         if self.policy.route and self.policy.compile_plan:
@@ -1850,6 +1930,11 @@ class PreparedStatement:
         if not self.policy.compile_plan:
             # eager policies have no device program to batch; stay serial
             return [self.execute(params=p) for p in params_list]
+        with span("froid.execute"):
+            return self._execute_many_compiled(params_list)
+
+    def _execute_many_compiled(self, params_list: list[dict]
+                               ) -> list[QueryResult]:
         env_token = self.session._env_token()
         groups: dict[tuple, list[int]] = {}
         for i, p in enumerate(params_list):
@@ -1913,51 +1998,58 @@ class PreparedStatement:
                                              sig, env_token, pending, mb)
                     return
                 bucket = batch_bucket(k, mb)
-        if shard:
-            entry, hit = self.session._sharded_executable(
-                self.node, self._query_fp, self.policy, plist[0], sig,
-                bucket, env_token,
-            )
-        else:
-            entry, hit = self.session._batched_executable(
-                self.node, self._query_fp, self.policy, plist[0], sig,
-                bucket, env_token,
-            )
         # runahead bound: past max_inflight unsynced chunks, sync the
         # oldest before issuing another dispatch (same backpressure rule
         # as execute_async — the host cannot queue unbounded device work)
         bound = max(1, self.policy.max_inflight)
         unsynced = [r for r in pending if not r["synced"]]
         while len(unsynced) >= bound:
-            oldest = unsynced.pop(0)
-            jax.block_until_ready(oldest["mask"])
-            oldest["synced"] = True
-        # pad to the bucket by repeating the last param set; padding rows
-        # are computed and discarded (never surfaced in results)
-        padded = plist + [plist[-1]] * (bucket - k)
+            self._sync_chunk(unsynced.pop(0))
+        sess = self.session
         t0 = time.perf_counter()
-        pargs = _stack_params(padded)
-        self.session._fault("dispatch", (self._query_fp,))
-        mask, cols = entry.fn(pargs, env_token[0])
-        t_dispatch = time.perf_counter() - t0
+        with span("froid.args"):
+            lookup = (sess._sharded_executable if shard
+                      else sess._batched_executable)
+            entry, hit = lookup(self.node, self._query_fp, self.policy,
+                                plist[0], sig, bucket, env_token)
+            t_ready = time.perf_counter()
+            # pad to the bucket by repeating the last param set; padding
+            # rows are computed and discarded (never surfaced in results)
+            padded = plist + [plist[-1]] * (bucket - k)
+            args = entry.args(_stack_params(padded), env_token[0])
+        t1 = time.perf_counter()
+        with span("froid.dispatch"):
+            sess._fault("dispatch", (self._query_fp,))
+            mask, cols = _run(entry, args, "shard" if shard else "batch")
+        t2 = time.perf_counter()
+        sess._timed(executions=1, args_s=t1 - t0, dispatch_s=t2 - t1)
         pending.append({
             "idxs": idxs, "entry": entry, "hit": hit, "mask": mask,
             "cols": cols, "k": k, "bucket": bucket, "shard": shard,
-            "devices": devices, "t0": t0, "dispatch_s": t_dispatch,
+            "devices": devices, "t0": t_ready, "dispatch_s": t2 - t_ready,
             "synced": False, "sig": sig,
         })
+
+    def _sync_chunk(self, rec: dict) -> float:
+        """Wait for a dispatched chunk's outputs; returns the clock after."""
+        t0 = time.perf_counter()
+        with span("froid.sync"):
+            jax.block_until_ready(rec["mask"])
+        t1 = time.perf_counter()
+        rec["synced"] = True
+        self.session._timed(sync_s=t1 - t0)
+        return t1
 
     def _finalize_batch(self, rec: dict, results: list,
                         pipelined: int) -> None:
         """Sync one dispatched chunk and build its QueryResults.
         ``sync_s`` is the wait from dispatch end to this chunk's barrier
         arrival — under pipelining that wait overlaps the later chunks'
-        host-side stacking, which is the point."""
+        host-side stacking, which is the point; the session's
+        ``timing_stats['sync_s']`` counts the wait itself."""
         entry, mask, cols = rec["entry"], rec["mask"], rec["cols"]
         self.session._fault("sync", (self._query_fp,))
-        jax.block_until_ready(mask)
-        rec["synced"] = True
-        elapsed = time.perf_counter() - rec["t0"]
+        elapsed = self._sync_chunk(rec) - rec["t0"]
         stats = {
             **entry.stats, "compiled": True, "batched": True,
             "batch_size": rec["k"], "batch_bucket": rec["bucket"],
@@ -1978,6 +2070,7 @@ class PreparedStatement:
                                 rec["bucket"], elapsed, rec["k"],
                                 shard=rec["shard"])
 
+        @self.session._timed_materialize
         def materialize(j: int) -> MaskedTable:
             table = Table(
                 {n: Column(data[j], valid[j], entry.out_dicts.get(n))
@@ -2012,55 +2105,82 @@ class PreparedStatement:
                 return target.execute_async(params=params)
         if not (self.policy.compile_plan and self.policy.allow_async):
             return AsyncResult(self.execute(params=params))
-        self.session._admit_async(self.policy.max_inflight)
-        env_token = self.session._env_token()
-        entry, exec_hit, plan_hit = self.session._executable(
-            self.node, self._query_fp, self.policy, params, env_token
-        )
-        t0 = time.perf_counter()
-        self.session._fault("dispatch", (self._query_fp,))
-        mask, cols = entry.fn(params, env_token[0])
-        dispatch_s = time.perf_counter() - t0
+        sess = self.session
+        sess._admit_async(self.policy.max_inflight)
+        with span("froid.execute"):
+            t0 = time.perf_counter()
+            with span("froid.args"):
+                env_token = sess._env_token()
+                entry, exec_hit, plan_hit = sess._executable(
+                    self.node, self._query_fp, self.policy, params, env_token
+                )
+                t_ready = time.perf_counter()
+                args = entry.args(params, env_token[0])
+            t1 = time.perf_counter()
+            with span("froid.dispatch"):
+                sess._fault("dispatch", (self._query_fp,))
+                mask, cols = _run(entry, args, "exec")
+            t2 = time.perf_counter()
+        sess._timed(executions=1, args_s=t1 - t0, dispatch_s=t2 - t1)
+        dispatch_s = t2 - t_ready
         stats = {**entry.stats, "compiled": True, "async": True,
                  "dispatch_s": dispatch_s}
         result: QueryResult
 
         def materialize() -> MaskedTable:
-            t1 = time.perf_counter()
-            jax.block_until_ready(mask)
-            sync_s = time.perf_counter() - t1
-            result.stats["sync_s"] = sync_s
-            result.elapsed_s = dispatch_s + sync_s
-            table = Table(
-                {n: Column(data, valid, entry.out_dicts.get(n))
-                 for n, (data, valid) in cols.items()}
-            )
+            with span("froid.materialize"):
+                t3 = time.perf_counter()
+                with span("froid.sync"):
+                    jax.block_until_ready(mask)
+                t4 = time.perf_counter()
+                table = Table(
+                    {n: Column(data, valid, entry.out_dicts.get(n))
+                     for n, (data, valid) in cols.items()}
+                )
+                t5 = time.perf_counter()
+            sess._timed(sync_s=t4 - t3, materializations=1,
+                        materialize_s=t5 - t4)
+            result.stats["sync_s"] = t4 - t3
+            result.elapsed_s = dispatch_s + t4 - t3
             return MaskedTable(table, mask)
 
         result = QueryResult(None, entry.plan, dispatch_s, stats,
                              policy=self.policy,
                              cache_hit=exec_hit and plan_hit,
                              materialize=materialize)
-        ar = AsyncResult(result, marker=mask, session=self.session)
-        self.session._inflight.append(ar)
-        self.session.async_stats["inflight_peak"] = max(
-            self.session.async_stats["inflight_peak"],
-            len(self.session._inflight),
+        ar = AsyncResult(result, marker=mask, session=sess)
+        sess._inflight.append(ar)
+        sess.async_stats["inflight_peak"] = max(
+            sess.async_stats["inflight_peak"], len(sess._inflight),
         )
         return ar
 
     def _execute_compiled(self, params) -> QueryResult:
-        env_token = self.session._env_token()
-        entry, exec_hit, plan_hit = self.session._executable(
-            self.node, self._query_fp, self.policy, params, env_token
-        )
-        t0 = time.perf_counter()
-        self.session._fault("dispatch", (self._query_fp,))
-        mask, cols = entry.fn(params, env_token[0])
-        self.session._fault("sync", (self._query_fp,))
-        jax.block_until_ready(mask)
-        elapsed = time.perf_counter() - t0
-        router = self.session.cost_router
+        sess = self.session
+        with span("froid.execute"):
+            t0 = time.perf_counter()
+            with span("froid.args"):
+                env_token = sess._env_token()
+                entry, exec_hit, plan_hit = sess._executable(
+                    self.node, self._query_fp, self.policy, params, env_token
+                )
+                t_ready = time.perf_counter()
+                args = entry.args(params, env_token[0])
+            t1 = time.perf_counter()
+            with span("froid.dispatch"):
+                sess._fault("dispatch", (self._query_fp,))
+                mask, cols = _run(entry, args, "exec")
+            t2 = time.perf_counter()
+            with span("froid.sync"):
+                sess._fault("sync", (self._query_fp,))
+                jax.block_until_ready(mask)
+            t3 = time.perf_counter()
+        sess._timed(executions=1, args_s=t1 - t0, dispatch_s=t2 - t1,
+                    sync_s=t3 - t2)
+        # from the executable in hand to the outputs ready, as the batched
+        # and async paths count it
+        elapsed = t3 - t_ready
+        router = sess.cost_router
         if router is not None:
             router.observe_serial(self._query_fp, self.policy, elapsed)
         table = Table(
@@ -2068,7 +2188,8 @@ class PreparedStatement:
              for n, (data, valid) in cols.items()}
         )
         masked = MaskedTable(table, mask)
-        stats = {**entry.stats, "compiled": True}
+        stats = {**entry.stats, "compiled": True,
+                 "dispatch_s": t2 - t_ready, "sync_s": t3 - t2}
         return QueryResult(masked, entry.plan, elapsed, stats,
                            policy=self.policy,
                            cache_hit=exec_hit and plan_hit)

@@ -50,6 +50,7 @@ from repro.resilience.ladder import (
     WaveGroup,
     WorkItem,
 )
+from repro.telemetry import span
 
 
 class Ticket:
@@ -111,6 +112,37 @@ class _Group:
         self.done_evt = threading.Event()
 
 
+class _WaveLock:
+    """The drain lock as one wave takes it.  Each wait for the lock is
+    timed (``lock_wait_s``, a ``froid.sched.lock_wait`` span), and the
+    first time the wave holds it ends its tickets' wait in the queue
+    (``queue_wait_s``, from each ticket's ``submitted_at``)."""
+
+    __slots__ = ("_sched", "_tickets")
+
+    def __init__(self, sched: "CoalescingScheduler", groups: list[_Group]):
+        self._sched = sched
+        self._tickets = [t for g in groups for t in g.tickets]
+
+    def __enter__(self):
+        sched = self._sched
+        t0 = sched.clock()
+        with span("froid.sched.lock_wait"):
+            sched._drain_lock.acquire()
+        t1 = sched.clock()
+        # the drain lock is held: waves count these one at a time
+        sched.stats["lock_wait_s"] += t1 - t0
+        if self._tickets:
+            sched.stats["queue_wait_s"] += sum(t1 - t.submitted_at
+                                               for t in self._tickets)
+            self._tickets = []
+        return self
+
+    def __exit__(self, *exc):
+        self._sched._drain_lock.release()
+        return False
+
+
 class CoalescingScheduler:
     """Accumulates concurrent same-statement requests into microbatches.
 
@@ -151,9 +183,14 @@ class CoalescingScheduler:
     ``submit`` overrides one.
 
     Stats (``self.stats``): submitted, batches, drained, flush reasons,
-    fused_batches / fused_statements, plus — under resilience — the ladder
-    counters (``demote_*``, ``tier_*_ok``, ``deadline_shed``,
-    ``breaker_open_skips``, ``retry_backoffs``, ``ladder_exhausted``).
+    fused_batches / fused_statements, the seconds tickets waited from
+    ``submit`` until their wave held the drain lock (``queue_wait_s``,
+    summed over tickets), the seconds waves waited for that lock
+    (``lock_wait_s``) and the seconds ``submit`` callers spent draining
+    (``submit_drain_s``), on the scheduler's clock, plus — under
+    resilience — the ladder counters (``demote_*``, ``tier_*_ok``,
+    ``deadline_shed``, ``breaker_open_skips``, ``retry_backoffs``,
+    ``ladder_exhausted``).
     ``resilience_stats`` bundles those with per-breaker state snapshots.
     """
 
@@ -191,6 +228,7 @@ class CoalescingScheduler:
             # waves whose fuse-or-not choice came from the cost router
             # (mixed-statement waves of routed statements only)
             "routed_waves": 0,
+            "queue_wait_s": 0.0, "lock_wait_s": 0.0, "submit_drain_s": 0.0,
         }
         self.ladder: DegradationLadder | None = None
         if resilience:
@@ -264,6 +302,7 @@ class CoalescingScheduler:
         :class:`~repro.resilience.faults.DeadlineExceeded` instead of
         executed (shed-before-drain)."""
         to_drain: list[_Group] = []
+        reason = "window"
         with self._lock:
             self.stats["submitted"] += 1
             now = self.clock()
@@ -282,8 +321,13 @@ class CoalescingScheduler:
                 self.stats["flush_full"] += 1
                 self._groups.pop(id(stmt), None)
                 to_drain.append(g)
+                reason = "full"
             to_drain.extend(self._take_expired_locked())
-        self._drain_all(to_drain)
+        if to_drain:
+            t0 = self.clock()
+            self._drain_all(to_drain, "submit", reason)
+            with self._lock:
+                self.stats["submit_drain_s"] += self.clock() - t0
         return t
 
     def poll(self) -> int:
@@ -292,7 +336,7 @@ class CoalescingScheduler:
         with self._lock:
             expired = self._take_expired_locked()
         n = sum(len(g.params) for g in expired)
-        self._drain_all(expired)
+        self._drain_all(expired, "poll", "window")
         return n
 
     def flush(self) -> int:
@@ -305,7 +349,7 @@ class CoalescingScheduler:
             if groups:
                 self.stats["flush_forced"] += len(groups)
         n = sum(len(g.params) for g in groups)
-        self._drain_all(groups)
+        self._drain_all(groups, "flush", "forced")
         return n
 
     @property
@@ -333,7 +377,7 @@ class CoalescingScheduler:
                 return  # already drained by another path
             self._groups.pop(id(group.stmt), None)
             self.stats["flush_forced"] += 1
-        self._drain_all([group])
+        self._drain_all([group], "result", "forced")
 
     def _route_fuse(self, groups: list[_Group]) -> bool:
         """Wave-level fuse-or-not routing.  When fusion drain mode is on,
@@ -355,26 +399,31 @@ class CoalescingScheduler:
         self.stats["routed_waves"] += 1
         return router.choose_fuse([(g.stmt, len(g.params)) for g in groups])
 
-    def _drain_all(self, groups: list[_Group]) -> None:
+    def _drain_all(self, groups: list[_Group], caller: str,
+                   reason: str) -> None:
         """Drain a set of batches that tripped together: through the
         degradation ladder under resilience (one fused wave when fusion
         drain mode is on and the wave is mixed-statement, demoting on
         failure), else the bare single-tier drains.  Routed waves may
-        override the fuse choice per wave (``_route_fuse``)."""
+        override the fuse choice per wave (``_route_fuse``).  ``caller``
+        (submit / poll / flush / result) and ``reason`` (full / window /
+        forced) label the wave's ``froid.sched.drain`` span."""
         if not groups:
             return
-        fuse = self._route_fuse(groups)
-        if self.ladder is not None:
-            self._drain_ladder(groups, fuse)
-            return
-        if fuse and len(groups) >= 2:
-            self._drain_fused(groups)
-            return
-        for g in groups:
-            self._drain(g)
+        with span("froid.sched.drain", n=sum(len(g.params) for g in groups),
+                  reason=reason, caller=caller):
+            lock = _WaveLock(self, groups)
+            fuse = self._route_fuse(groups)
+            if self.ladder is not None:
+                self._drain_ladder(groups, fuse, lock)
+            elif fuse and len(groups) >= 2:
+                self._drain_fused(groups, lock)
+            else:
+                for g in groups:
+                    self._drain(g, lock)
 
-    def _drain_ladder(self, groups: list[_Group],
-                      fuse: bool | None = None) -> None:
+    def _drain_ladder(self, groups: list[_Group], fuse: bool,
+                      lock: _WaveLock) -> None:
         """Ladder-backed drain: hand the wave to the resilience layer,
         then map every WorkItem outcome onto its ticket.  The ladder
         resolves every item with a result or a typed/raw error; an
@@ -386,8 +435,7 @@ class CoalescingScheduler:
             for g in groups
         ]
         try:
-            self.ladder.drain(wave, fuse=self.fuse if fuse is None else fuse,
-                              lock=self._drain_lock)
+            self.ladder.drain(wave, fuse=fuse, lock=lock)
         except BaseException as e:
             for g, wg in zip(groups, wave):
                 for t, it in zip(g.tickets, wg.items):
@@ -410,7 +458,7 @@ class CoalescingScheduler:
                 self._finish(g)
 
     # -- bare drains (resilience=False) --------------------------------------
-    def _drain_fused(self, groups: list[_Group]) -> None:
+    def _drain_fused(self, groups: list[_Group], lock: _WaveLock) -> None:
         """Mixed-statement drain through ``Session.execute_fused``, with
         **per-group error isolation**: when the fused wave fails (one
         member referencing a dropped table must not poison every ticket of
@@ -424,7 +472,7 @@ class CoalescingScheduler:
         self.stats["fused_statements"] += len(groups)
         calls = [(g.stmt, p) for g in groups for p in g.params]
         try:
-            with self._drain_lock:
+            with lock:
                 # execute_fused routes foreign-session / non-fusable
                 # statements back to their own per-statement path
                 results = groups[0].stmt.session.execute_fused(calls)
@@ -448,7 +496,7 @@ class CoalescingScheduler:
                 for g in groups:
                     self.stats["fused_isolated_retries"] += 1
                     try:
-                        with self._drain_lock, suppress():
+                        with lock, suppress():
                             rs = g.stmt.execute_many(g.params)
                         if len(rs) != len(g.tickets):
                             raise WaveResultMismatch(len(g.tickets), len(rs),
@@ -483,11 +531,11 @@ class CoalescingScheduler:
                 t.latency_s = now - t.submitted_at
         group.done_evt.set()
 
-    def _drain(self, group: _Group) -> None:
+    def _drain(self, group: _Group, lock: _WaveLock) -> None:
         self.stats["batches"] += 1
         self.stats["drained"] += len(group.params)
         try:
-            with self._drain_lock:
+            with lock:
                 results = group.stmt.execute_many(group.params)
             if len(results) != len(group.tickets):
                 raise WaveResultMismatch(len(group.tickets), len(results),
