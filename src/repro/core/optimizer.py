@@ -19,6 +19,12 @@ Rules (each is a semantics-preserving plan rewrite, unit-tested):
                             join / semi-join (the "optimizer infers the
                             joins and group-bys" step that makes plans
                             set-oriented, §5)
+* ``push_pinned_keys``    — a join whose left key a filter pins to a
+                            parameter or literal filters its build side on
+                            that value too, below the build's grouping
+* ``collapse_pinned_groupaggs`` — a GroupAgg whose every key is pinned has
+                            one group at most: a keyless aggregate, its
+                            keys set to the pinned values
 """
 from __future__ import annotations
 
@@ -961,6 +967,165 @@ def decorrelate_filters(plan: R.RelNode, catalog=None):
 
 
 # ---------------------------------------------------------------------------
+# pinned keys: equality filters against a parameter or a literal
+# ---------------------------------------------------------------------------
+
+#: float32 holds every integer of at most this magnitude exactly, so an
+#: integer column inside it equals a value of any numeric type (an int32 or
+#: float32 parameter, a literal) at one key value at most
+_EXACT_INT = 1 << 24
+
+#: the row count a collapsed GroupAgg keeps so that no input row still
+#: makes no group; the name marks the collapse for EXPLAIN and the counter
+PINNED_COUNT = "__pinned_n"
+
+
+def _pin_value(e: S.Scalar) -> S.Scalar | None:
+    """``e`` where it is a parameter or a non-NULL numeric literal."""
+    if isinstance(e, S.Param):
+        return e
+    if (isinstance(e, S.Const) and e.value is not None
+            and not isinstance(e.value, str)):
+        return e
+    return None
+
+
+def _int_keys(node: R.RelNode, catalog) -> dict[str, tuple]:
+    """``{column: (dtype, pin)}`` for the output columns of ``node`` that
+    carry a base table's integer column value for value (not dictionary
+    coded, inside ``±_EXACT_INT`` by the table's stats).  ``pin`` is the
+    parameter or literal that an equality filter at or below ``node`` makes
+    every row's value equal, else None.  Pins carry through renames,
+    aliases and integer casts, filters, sorts, a join's left side and
+    grouping keys."""
+    if isinstance(node, R.Scan):
+        t = catalog.get(node.table)
+        out = {}
+        for c, col in ({} if t is None else t.columns).items():
+            if (col.dictionary is not None
+                    or not jnp.issubdtype(col.data.dtype, jnp.integer)):
+                continue
+            st = t.stats.get(c)
+            if st is not None and -_EXACT_INT <= st[1] and st[2] <= _EXACT_INT:
+                out[c] = (np.dtype(col.data.dtype), None)
+        return out
+    if isinstance(node, R.Sort):
+        return _int_keys(node.child, catalog)
+    if isinstance(node, R.Filter):
+        out = _int_keys(node.child, catalog)
+        for p in _split_conjuncts(node.pred):
+            if not (isinstance(p, S.Cmp) and p.op == "=="):
+                continue
+            for c, v in ((p.l, p.r), (p.r, p.l)):
+                v = _pin_value(v)
+                if (v is not None and isinstance(c, S.ColRef)
+                        and c.name in out and out[c.name][1] is None):
+                    out[c.name] = (out[c.name][0], v)
+        return out
+    if isinstance(node, R.Compute):
+        out = _int_keys(node.child, catalog)
+        for name, e in node.computed.items():
+            src, dtype = e, None
+            if (isinstance(e, S.Cast) and jnp.issubdtype(e.dtype, jnp.integer)
+                    and np.dtype(e.dtype).itemsize >= 4):
+                src, dtype = e.expr, np.dtype(e.dtype)
+            hit = out.get(src.name) if isinstance(src, S.ColRef) else None
+            out.pop(name, None)
+            if hit is not None:
+                out[name] = (dtype or hit[0], hit[1])
+        return out
+    if isinstance(node, R.Project):
+        inner = _int_keys(node.child, catalog)
+        return {new: inner[old] for new, old in node.cols.items()
+                if old in inner}
+    if isinstance(node, R.Join):
+        return _int_keys(node.left, catalog)
+    if isinstance(node, R.GroupAgg):
+        inner = _int_keys(node.child, catalog)
+        return {k: inner[k] for k in node.keys if k in inner}
+    return {}
+
+
+def _push_pin(node: R.RelNode, col: str, pin: S.Scalar) -> R.RelNode:
+    """``Filter(node, col == pin)``, placed below the renames and the
+    GroupAggs grouping on ``col`` that sit on top of ``node``: filtering on
+    a grouping key commutes with the grouping."""
+    if isinstance(node, R.Project):
+        return node.with_children([_push_pin(node.child, node.cols[col], pin)])
+    if isinstance(node, R.GroupAgg) and col in node.keys:
+        return node.with_children([_push_pin(node.child, col, pin)])
+    return R.Filter(node, S.Cmp("==", S.ColRef(col), pin))
+
+
+def push_pinned_keys(plan: R.RelNode, catalog=None):
+    """Join(L, R) on ``l = r`` with L's ``l`` pinned to a parameter or
+    literal ``v`` → Join(L, Filter(R, r == v)), the filter pushed down R
+    (:func:`_push_pin`).  Sound for inner, left, semi and anti joins: every
+    surviving left row has key ``v``, so no right row with another key can
+    match one.  A right key that is pinned already is left alone, so the
+    rule reaches a fixpoint."""
+    if not catalog:
+        return plan, False
+    changed = [False]
+
+    def rule(node: R.RelNode):
+        if not isinstance(node, R.Join):
+            return None
+        lkeys = _int_keys(node.left, catalog)
+        right = node.right
+        for lk, rk in node.on:
+            pin = lkeys.get(lk, (None, None))[1]
+            rkeys = _int_keys(right, catalog)
+            if pin is None or rk not in rkeys or rkeys[rk][1] is not None:
+                continue
+            right = _push_pin(right, rk, pin)
+        if right is node.right:
+            return None
+        changed[0] = True
+        return R.Join(node.left, right, node.on, node.kind)
+
+    return R.transform_plan(plan, rule), changed[0]
+
+
+def collapse_pinned_groupaggs(plan: R.RelNode, catalog=None):
+    """GroupAgg(X, keys=[k…], aggs) with every key pinned to ``v…`` in X →
+    Project(Filter(Compute(GroupAgg(X, [], aggs + {n: count_star}),
+    {k: Cast(v, k's dtype)…}), n > 0), [k…, aggs…]).  X's rows all share
+    one key, so there is one group, or none where no row matched: ``n > 0``
+    keeps that (a left join then still misses, and COUNT/EXISTS keep their
+    Coalesce-to-0 meaning).  The executor runs the keyless aggregate as one
+    masked reduction per aggregate, with no sort and no scatter, and a join
+    above it probes a one-row build."""
+    if not catalog:
+        return plan, False
+    changed = [False]
+
+    def rule(node: R.RelNode):
+        if (not isinstance(node, R.GroupAgg) or not node.keys
+                or PINNED_COUNT in node.keys or PINNED_COUNT in node.aggs):
+            return None
+        keys = _int_keys(node.child, catalog)
+        if any(keys.get(k, (None, None))[1] is None for k in node.keys):
+            return None
+        aggs = dict(node.aggs)
+        aggs[PINNED_COUNT] = R.AggSpec("count_star", None)
+        out = R.Compute(R.GroupAgg(node.child, [], aggs),
+                        {k: S.Cast(keys[k][1], keys[k][0]) for k in node.keys})
+        out = R.Filter(out, S.Cmp(">", S.ColRef(PINNED_COUNT), S.Const(0)))
+        changed[0] = True
+        return R.Project(out, node.keys + list(node.aggs))
+
+    return R.transform_plan(plan, rule), changed[0]
+
+
+def pinned_groupaggs(plan: R.RelNode) -> int:
+    """How many GroupAggs of ``plan`` :func:`collapse_pinned_groupaggs`
+    collapsed, subquery plans included."""
+    return sum(isinstance(n, R.GroupAgg) and not n.keys
+               and PINNED_COUNT in n.aggs for n in R.walk_plan_deep(plan))
+
+
+# ---------------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------------
 
@@ -1026,6 +1191,8 @@ DEFAULT_RULES = (
     propagate_constants,
     decorrelate_in_computes,
     decorrelate_filters,
+    push_pinned_keys,
+    collapse_pinned_groupaggs,
     annotate_group_stats,
 )
 

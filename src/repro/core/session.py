@@ -739,9 +739,11 @@ class Session:
         # (executable lookup, parameter packing, catalog arrays), its
         # dispatch and its sync; per lazy result, its materialization.  A
         # call that compiles holds the compile in its args_s (AOT or store
-        # load) or dispatch_s (jit); repro.telemetry counts compiles apart
+        # load) or dispatch_s (jit); repro.telemetry counts compiles apart.
+        # Per plan that prepare builds, the GroupAggs the optimizer
+        # collapsed on pinned keys
         self.timing_stats = {
-            "tables": 0, "catalog_s": 0.0,
+            "tables": 0, "catalog_s": 0.0, "pinned_groupaggs": 0,
             "executions": 0, "args_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0,
             "materializations": 0, "materialize_s": 0.0,
         }
@@ -1057,6 +1059,7 @@ class Session:
             plan = O.optimize(
                 plan, self.catalog, required=set(wanted) if wanted else None
             )
+            self._timed(pinned_groupaggs=O.pinned_groupaggs(plan))
         if wanted is not None:
             try:
                 have = R.output_columns(plan, self.catalog)
