@@ -496,24 +496,26 @@ def phase_warm_start(db, store_dir, tpch_warm, served, dev):
 
 
 def phase_sharded(args, devices):
-    """``--chips 4``: one statement's batch under ``FROID.sharded`` over a
-    four-device mesh, against the same batch on one device."""
+    """``--chips 4``: one statement's batch under ``"FROID+data4"`` (the
+    placement the ``udf_calls.open.x4`` cell names), against the same batch
+    on one device."""
     import jax
     import numpy as np
 
-    from repro.core import FROID
+    from repro.core import FROID, resolve_policy
     from repro.dist.sharding import batch_sharding
 
     if len(devices) < 4:
         raise SystemExit(f"chip_smoke --chips 4: {len(devices)} devices")
-    mesh = jax.make_mesh((4,), ("data",), devices=devices[:4])
+    policy = resolve_policy("FROID+data4")
+    mesh = policy.mesh
     t0 = time.perf_counter()
     db = load_session(args.sf, args.seed)
     log("load", f"sf={args.sf} load_s={time.perf_counter() - t0:.2f}")
     builder, bindings = served_statements()["q6_cutoff"]
     plist = bindings(np.random.default_rng(args.seed), N_BINDINGS,
                      db.catalog["customer"].num_rows)
-    sharded = db.prepare(builder(), FROID.sharded(mesh))
+    sharded = db.prepare(builder(), policy)
     t0 = time.perf_counter()
     rs = sharded.execute_many(plist)
     for r in rs:

@@ -18,6 +18,7 @@ Table 5 quadrants:
 from __future__ import annotations
 
 import dataclasses
+import re
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,8 +71,8 @@ class ExecutionPolicy:
     #: single default device; axes named per repro.dist.sharding)
     mesh: object = dataclasses.field(default=None, compare=False, repr=False)
     #: shard the stacked parameter axis of `execute_many` buckets over the
-    #: mesh's data axes; divisibility-gated per bucket — buckets the data
-    #: axes don't divide run on the replicated single-device path
+    #: mesh's data axes; a bucket the data axes don't divide pads up to the
+    #: next multiple of their product, so every wave uses the whole mesh
     shard_batches: bool = dataclasses.field(default=False, compare=False)
 
     # -- multi-statement fusion knobs (tuning like the batch/shard knobs:
@@ -250,15 +251,41 @@ ROUTED = dataclasses.replace(FROID, name="routed", route=True)
 PRESETS = {p.name: p for p in (FROID, INTERPRETED, HEKATON, ROUTED)}
 
 
+#: a preset name followed by a data-axis placement: ``"FROID+data4"``
+_PLACED = re.compile(r"(?P<preset>[^+]+)\+data(?P<n>[1-9][0-9]*)")
+
+
+def data_mesh(n: int):
+    """A mesh with one ``data`` axis over the first ``n`` local devices,
+    as ``chip_smoke.py --chips 4`` builds it (``repro.dist.sharding``
+    retypes its axes ``Auto`` where it places batches)."""
+    import jax
+
+    devices = jax.local_devices()
+    if len(devices) < n:
+        raise ValueError(
+            f"placement data{n} needs {n} local devices; JAX has "
+            f"{len(devices)} ({devices[0].platform})")
+    return jax.make_mesh((n,), ("data",), devices=devices[:n])
+
+
 def resolve_policy(policy) -> ExecutionPolicy:
-    """Accept an ExecutionPolicy or a preset name."""
+    """Accept an ExecutionPolicy, a preset name, or a preset name with a
+    data-axis placement: ``"FROID+data4"`` is ``FROID.sharded`` over a
+    :func:`data_mesh` of the first four local devices, so every
+    ``execute_many`` wave spreads its parameter axis over them."""
     if isinstance(policy, ExecutionPolicy):
         return policy
     if isinstance(policy, str):
+        placed = _PLACED.fullmatch(policy)
+        name = placed["preset"] if placed else policy
         try:
-            return PRESETS[policy.lower()]
+            preset = PRESETS[name.lower()]
         except KeyError:
             raise KeyError(
-                f"unknown policy preset {policy!r}; have {sorted(PRESETS)}"
+                f"unknown policy preset {name!r}; have {sorted(PRESETS)}, "
+                f"each optionally followed by +data<N>"
             ) from None
+        return preset.sharded(data_mesh(int(placed["n"]))) if placed \
+            else preset
     raise TypeError(f"policy must be ExecutionPolicy or str, got {type(policy)}")

@@ -346,21 +346,25 @@ def _pool_pad(d: int) -> int:
     return b
 
 
-def _stack_params(params_list: list[dict]) -> dict:
+def _stack_params(params_list: list[dict], host: bool = False) -> dict:
     """Stack same-signature param dicts into one batched argument pytree:
     name -> (data (B, …), valid (B, …)).  Scalars take the numpy fast path
-    (one host array per name, not B device scalars)."""
+    (one host array per name, not B device scalars); with ``host`` they
+    stay on the host, for a caller that places them on a mesh itself (one
+    transfer to each device, not one to the default device and a
+    reshard)."""
+    xp = np if host else jnp
     first = params_list[0]
     out = {}
     for name in sorted(first):
         vs = [p[name] for p in params_list]
         v0 = vs[0]
         if isinstance(v0, bool):
-            data = jnp.asarray(np.asarray(vs, dtype=bool))
+            data = xp.asarray(np.asarray(vs, dtype=bool))
         elif isinstance(v0, (int, np.integer)):
-            data = jnp.asarray(np.asarray(vs), jnp.int32)
+            data = xp.asarray(np.asarray(vs), np.int32)
         elif isinstance(v0, (float, np.floating)):
-            data = jnp.asarray(np.asarray(vs), jnp.float32)
+            data = xp.asarray(np.asarray(vs), np.float32)
         else:
             vals = [_param_value(v) for v in vs]
             out[name] = (
@@ -368,7 +372,7 @@ def _stack_params(params_list: list[dict]) -> dict:
                 jnp.stack([v.validity() for v in vals]),
             )
             continue
-        out[name] = (data, jnp.ones((len(vs),), bool))
+        out[name] = (data, xp.ones((len(vs),), bool))
     return out
 
 
@@ -630,6 +634,23 @@ def _run(entry: _Program, args: tuple, tier: str):
     return out
 
 
+def _row(x, j: int):
+    """Row ``j`` of a batched output."""
+    return x[j]
+
+
+def _shard_row(x, j: int):
+    """Row ``j`` of a batched output sharded over a mesh, sliced on the one
+    device that holds it: indexing the sharded array itself runs an SPMD
+    program that moves the row across devices."""
+    for s in x.addressable_shards:
+        rows = s.index[0] if s.index else slice(None)
+        lo = rows.start or 0
+        if lo <= j and (rows.stop is None or j < rows.stop):
+            return s.data[j - lo]
+    raise IndexError(f"row {j} is on no addressable device")
+
+
 @dataclasses.dataclass
 class _FuseMember:
     """One member of a fused program: a (statement plan, parameter
@@ -741,11 +762,14 @@ class Session:
         # call that compiles holds the compile in its args_s (AOT or store
         # load) or dispatch_s (jit); repro.telemetry counts compiles apart.
         # Per plan that prepare builds, the GroupAggs the optimizer
-        # collapsed on pinned keys
+        # collapsed on pinned keys.  Per batched or fused program run, its
+        # placement: programs run over a mesh and the calls they answered,
+        # and the padding rows its bucket added (power-of-two and mesh)
         self.timing_stats = {
             "tables": 0, "catalog_s": 0.0, "pinned_groupaggs": 0,
             "executions": 0, "args_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0,
             "materializations": 0, "materialize_s": 0.0,
+            "sharded_waves": 0, "sharded_calls": 0, "pad_calls": 0,
         }
         # lazy results materialize on their consumers' threads
         self._timing_lock = threading.Lock()
@@ -1303,10 +1327,8 @@ class Session:
         program as :meth:`_batched_executable`, but jitted with the stacked
         parameter axis sharded over the mesh's data axes
         (``repro.dist.sharding.pick_data_axes``) and the catalog replicated
-        on every device.  Callers gate on divisibility: a bucket the data
-        axes don't divide never reaches here (it runs on the replicated
-        single-device path instead — rows are never padded onto a mesh
-        that doesn't fit them)."""
+        on every device.  Callers pad the bucket to a multiple of the
+        data-axis product first (``_dispatch_batch``)."""
         from repro.dist.sharding import batch_sharding
 
         if env_token is None:
@@ -1323,7 +1345,7 @@ class Session:
         base, _, _ = self._executable(node, query_fp, policy, params0, env_token)
         mesh = policy.mesh
         parg_sharding = batch_sharding(mesh, bucket)
-        if parg_sharding is None:  # callers gate; keep the invariant loud
+        if parg_sharding is None:  # callers pad; keep the invariant loud
             raise ValueError(
                 f"bucket {bucket} is not divisible by the mesh data axes"
             )
@@ -1706,6 +1728,10 @@ class Session:
         )
         self.cache_stats["cse_shared_nodes"] += m_stats["cse_shared_nodes"]
         n_tickets = sum(len(by_key[k]["idxs"]) for k in order)
+        self._timed(sharded_waves=int(shard),
+                    sharded_calls=n_tickets if shard else 0,
+                    pad_calls=sum(m.bucket - len(by_key[k]["params"])
+                                  for m, k in zip(members, order) if m.sig))
         router = self.cost_router
         if router is not None:
             router.observe_fused(
@@ -1766,12 +1792,14 @@ class Session:
                     )
                 continue
 
-            def materialize(row, mask=mask, cols=cols, out_dicts=out_dicts):
+            def materialize(row, mask=mask, cols=cols, out_dicts=out_dicts,
+                            take=_shard_row if shard else _row):
                 table = Table(
-                    {n: Column(data[row], valid[row], out_dicts.get(n))
+                    {n: Column(take(data, row), take(valid, row),
+                               out_dicts.get(n))
                      for n, (data, valid) in cols.items()}
                 )
-                return MaskedTable(table, mask[row])
+                return MaskedTable(table, take(mask, row))
 
             mat = self._timed_materialize(materialize)
             for row, i in enumerate(ent["idxs"]):
@@ -1906,10 +1934,11 @@ class PreparedStatement:
         A policy carrying a mesh (``policy.sharded(mesh)``) shards the
         stacked parameter axis over the mesh's data axes: ``max_batch``
         bounds the *per-device* batch, so one mesh dispatch carries up to
-        ``max_batch × shard_devices()`` parameter sets.  Sharding is
-        divisibility-gated per bucket — buckets the data axes don't divide
-        (small remainders, tiny batches) run on the replicated
-        single-device path, never padded onto a mesh that doesn't fit.
+        ``max_batch × shard_devices()`` parameter sets.  Every bucket runs
+        on the whole mesh: one the data axes don't divide (small
+        remainders, tiny batches) pads up to the next multiple of their
+        product by repeating its last parameter set, and each result is
+        sliced from the device shard that holds its row.
 
         Chunked dispatches are **pipelined**: every chunk is dispatched
         before any chunk syncs (bounded by ``policy.max_inflight`` unsynced
@@ -1969,38 +1998,27 @@ class PreparedStatement:
         return results  # type: ignore[return-value]
 
     def _dispatch_batch(self, idxs: list[int], plist: list[dict], sig: tuple,
-                        env_token: tuple, pending: list,
-                        cap: int | None = None) -> None:
+                        env_token: tuple, pending: list, cap: int) -> None:
         """Dispatch one chunk (no sync) and append its record to
         ``pending`` for the caller's end-of-call barrier."""
         k = len(plist)
-        cap_b = cap if cap is not None else self.policy.max_batch
-        bucket = batch_bucket(k, cap_b)
+        bucket = batch_bucket(k, cap)
+        devices = self.policy.shard_devices()
+        shard = devices > 1
         router = self.session.cost_router
         if router is not None and self.policy.route:
             # bucket routing: ride an already-measured larger bucket when
             # that beats cold-compiling the natural one (bucket ≥ k always
             # holds — rides only go up, and padding repeats the last set)
-            bucket = router.choose_bucket(
-                self, sig, k, bucket, cap_b,
-                shard=self.policy.shard_devices() > 1)
-        devices = self.policy.shard_devices()
-        shard = False
-        if devices > 1:
-            from repro.dist.sharding import pick_data_axes
-
-            shard = pick_data_axes(self.policy.mesh, bucket) is not None
-            if not shard:
-                # replicated fallback: the mesh-capacity bucket would land
-                # whole on one device, so re-chunk to the per-device bound
-                # (max_batch is a single-device promise, not just a knob)
-                mb = max(1, self.policy.max_batch)
-                if k > mb:
-                    for s in range(0, k, mb):
-                        self._dispatch_batch(idxs[s:s + mb], plist[s:s + mb],
-                                             sig, env_token, pending, mb)
-                    return
-                bucket = batch_bucket(k, mb)
+            bucket = router.choose_bucket(self, sig, k, bucket, cap,
+                                          shard=shard)
+        if shard:
+            # every wave uses the mesh: a bucket the data axes don't divide
+            # pads up to the next multiple of their product, as the fused
+            # path pads a non-dividing member.  The cap is max_batch ×
+            # devices, itself such a multiple, so the per-device batch
+            # stays within max_batch
+            bucket += (-bucket) % devices
         # runahead bound: past max_inflight unsynced chunks, sync the
         # oldest before issuing another dispatch (same backpressure rule
         # as execute_async — the host cannot queue unbounded device work)
@@ -2019,13 +2037,15 @@ class PreparedStatement:
             # pad to the bucket by repeating the last param set; padding
             # rows are computed and discarded (never surfaced in results)
             padded = plist + [plist[-1]] * (bucket - k)
-            args = entry.args(_stack_params(padded), env_token[0])
+            args = entry.args(_stack_params(padded, host=shard), env_token[0])
         t1 = time.perf_counter()
-        with span("froid.dispatch"):
+        with span("froid.dispatch", devices=devices):
             sess._fault("dispatch", (self._query_fp,))
             mask, cols = _run(entry, args, "shard" if shard else "batch")
         t2 = time.perf_counter()
-        sess._timed(executions=1, args_s=t1 - t0, dispatch_s=t2 - t1)
+        sess._timed(executions=1, args_s=t1 - t0, dispatch_s=t2 - t1,
+                    sharded_waves=int(shard), sharded_calls=k if shard else 0,
+                    pad_calls=bucket - k)
         pending.append({
             "idxs": idxs, "entry": entry, "hit": hit, "mask": mask,
             "cols": cols, "k": k, "bucket": bucket, "shard": shard,
@@ -2073,13 +2093,16 @@ class PreparedStatement:
                                 rec["bucket"], elapsed, rec["k"],
                                 shard=rec["shard"])
 
+        row = _shard_row if rec["shard"] else _row
+
         @self.session._timed_materialize
         def materialize(j: int) -> MaskedTable:
             table = Table(
-                {n: Column(data[j], valid[j], entry.out_dicts.get(n))
+                {n: Column(row(data, j), row(valid, j),
+                           entry.out_dicts.get(n))
                  for n, (data, valid) in cols.items()}
             )
-            return MaskedTable(table, mask[j])
+            return MaskedTable(table, row(mask, j))
 
         for j, i in enumerate(rec["idxs"]):
             results[i] = QueryResult(

@@ -80,6 +80,28 @@ class ResilienceConfig:
     interp_fallback: bool = True
 
 
+#: ``Session.timing_stats`` counters of where a wave's programs ran
+PLACEMENT = ("sharded_waves", "sharded_calls", "pad_calls")
+
+
+@contextlib.contextmanager
+def counting_placement(session, bump: Callable[[str, int], None]):
+    """Count the placement of one wave's programs run inside: one sharded
+    wave however many mesh programs it took, the calls those answered, and
+    the padding rows of every bucket.  Entered under the drain lock, so
+    the session's counters move for this wave alone; a wave that raises
+    counts nothing."""
+    stats = session.timing_stats
+    before = [stats[k] for k in PLACEMENT]
+    yield
+    waves, calls, pad = (stats[k] - b for k, b in zip(PLACEMENT, before))
+    if waves:
+        bump("sharded_waves", 1)
+        bump("sharded_calls", calls)
+    if pad:
+        bump("pad_calls", pad)
+
+
 class _NullLock:
     def __enter__(self):
         return self
@@ -239,7 +261,8 @@ class DegradationLadder:
             try:
                 # retries are fault-window runs (something already failed
                 # once); only the first attempt may train the cost model
-                with lock, self._sample_guard(session, attempt > 1):
+                with lock, self._sample_guard(session, attempt > 1), \
+                        counting_placement(session, self._bump):
                     results = session.execute_fused(calls)
                 if len(results) != len(calls):
                     raise WaveResultMismatch(len(calls), len(results),
@@ -302,7 +325,8 @@ class DegradationLadder:
         retry = self.config.retry
         for attempt in range(1, retry.max_attempts + 1):
             try:
-                with lock, self._sample_guard(g.stmt.session, attempt > 1):
+                with lock, self._sample_guard(g.stmt.session, attempt > 1), \
+                        counting_placement(g.stmt.session, self._bump):
                     results = g.stmt.execute_many([it.params for it in live])
                 if len(results) != len(live):
                     raise WaveResultMismatch(len(live), len(results),

@@ -49,6 +49,7 @@ from repro.resilience.ladder import (
     ResilienceConfig,
     WaveGroup,
     WorkItem,
+    counting_placement,
 )
 from repro.telemetry import span
 
@@ -187,7 +188,9 @@ class CoalescingScheduler:
     ``submit`` until their wave held the drain lock (``queue_wait_s``,
     summed over tickets), the seconds waves waited for that lock
     (``lock_wait_s``) and the seconds ``submit`` callers spent draining
-    (``submit_drain_s``), on the scheduler's clock, plus — under
+    (``submit_drain_s``), on the scheduler's clock, where waves ran
+    (``sharded_waves`` and ``sharded_calls`` on a mesh, ``pad_calls``
+    padding rows their buckets added), plus — under
     resilience — the ladder counters (``demote_*``, ``tier_*_ok``,
     ``deadline_shed``, ``breaker_open_skips``, ``retry_backoffs``,
     ``ladder_exhausted``).
@@ -229,6 +232,9 @@ class CoalescingScheduler:
             # (mixed-statement waves of routed statements only)
             "routed_waves": 0,
             "queue_wait_s": 0.0, "lock_wait_s": 0.0, "submit_drain_s": 0.0,
+            # where waves ran: waves and calls on the whole mesh, padding
+            # rows the waves' buckets added (ladder.counting_placement)
+            "sharded_waves": 0, "sharded_calls": 0, "pad_calls": 0,
         }
         self.ladder: DegradationLadder | None = None
         if resilience:
@@ -246,6 +252,9 @@ class CoalescingScheduler:
                 "tier_fused_ok": 0, "tier_many_ok": 0,
                 "tier_serial_ok": 0, "tier_interp_ok": 0,
             })
+
+    def _bump(self, key: str, n: int) -> None:
+        self.stats[key] += n
 
     # -- knob resolution ----------------------------------------------------
     def _max_batch(self, stmt: PreparedStatement) -> int:
@@ -472,10 +481,11 @@ class CoalescingScheduler:
         self.stats["fused_statements"] += len(groups)
         calls = [(g.stmt, p) for g in groups for p in g.params]
         try:
-            with lock:
+            session = groups[0].stmt.session
+            with lock, counting_placement(session, self._bump):
                 # execute_fused routes foreign-session / non-fusable
                 # statements back to their own per-statement path
-                results = groups[0].stmt.session.execute_fused(calls)
+                results = session.execute_fused(calls)
             if len(results) != len(calls):
                 # a protocol violation must fail the wave with a typed
                 # error, not leak StopIteration from the zip below
@@ -535,7 +545,7 @@ class CoalescingScheduler:
         self.stats["batches"] += 1
         self.stats["drained"] += len(group.params)
         try:
-            with lock:
+            with lock, counting_placement(group.stmt.session, self._bump):
                 results = group.stmt.execute_many(group.params)
             if len(results) != len(group.tickets):
                 raise WaveResultMismatch(len(group.tickets), len(results),

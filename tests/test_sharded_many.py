@@ -1,15 +1,16 @@
 """Mesh-sharded `execute_many`: the batched invocation engine one level up
 the hardware hierarchy.
 
-Covers the ISSUE-3 contract: element-wise identity between the sharded
-path and the serial `execute` loop, divisibility gating (buckets the mesh's
-data axes don't divide run on the replicated path), the sharded-executable
-cache tier (`shard_hits`/`shard_misses`), mesh-capacity chunking
+Covers the contract: element-wise identity between the sharded path and
+the serial `execute` loop, padding (a bucket the mesh's data axes don't
+divide pads up to a multiple of them and runs on the mesh), the
+sharded-executable cache tier (`shard_hits`/`shard_misses`), mesh-capacity
+chunking
 (`max_batch` bounds the per-device batch), mesh-sized scheduler flushes,
 catalog invalidation of sharded executables, and the sharded admission
 path of the serving engine.
 
-Every test passes on a single device (sharding degrades to the replicated
+Every test passes on a single device (sharding degrades to the one-device
 path) and is exercised for real under the CI job that forces
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
@@ -209,13 +210,20 @@ def test_empty_aggregate_source_table_runs():
 
 @multi_device
 def test_small_bucket_runs_replicated(db):
-    """A bucket the data axes don't divide (here bucket 1 < devices) must
-    run on the replicated single-device path, never padded to the mesh."""
+    """A bucket the data axes don't divide (here bucket 1 < devices) pads
+    up to the next multiple of the data-axis product by repeating its last
+    parameter set, and runs on the whole mesh, never on one device."""
     stmt = db.prepare(_q(), FROID.sharded(_mesh()))
+    before = db.timing_stats["pad_calls"]
     rs = stmt.execute_many([{"cutoff": 7}])
-    assert "sharded" not in rs[0].stats
-    assert db.cache_stats["shard_misses"] == 0
     assert pick_data_axes(_mesh(), 1) is None
+    st = rs[0].stats
+    assert st["sharded"] and st["shard_devices"] == N_DEV
+    assert st["batch_size"] == 1 and st["batch_bucket"] == N_DEV
+    assert db.cache_stats["shard_misses"] == 1
+    assert db.cache_stats["batch_misses"] == 0
+    assert db.timing_stats["pad_calls"] - before == N_DEV - 1
+    _assert_same([stmt.execute(params={"cutoff": 7})], rs)
 
 
 @multi_device
@@ -238,18 +246,20 @@ def test_shard_cache_tier_hits(db):
 
 @pytest.mark.skipif(N_DEV < 8, reason="needs 8 forced devices")
 def test_replicated_fallback_respects_max_batch(db):
-    """A bucket the data axes don't divide falls back to the replicated
-    path re-chunked at the *per-device* bound — the mesh-capacity cap must
-    never land whole on one device."""
+    """A bucket the data axes don't divide pads to the next multiple of
+    their product, and the per-device bound holds: the mesh-capacity cap
+    is itself such a multiple, so no device gets more than max_batch."""
     from jax.sharding import Mesh
 
     mesh6 = Mesh(np.array(jax.devices()[:6]), ("data",))
     stmt = db.prepare(_q(), FROID.sharded(mesh6).batched(max_batch=2))
     plist = [{"cutoff": int(k)} for k in range(5)]  # bucket 8, 8 % 6 != 0
     rs = stmt.execute_many(plist)
-    assert all("sharded" not in r.stats for r in rs)
-    assert all(r.stats["batch_bucket"] <= 2 for r in rs)
-    assert [r.stats["batch_size"] for r in rs] == [2, 2, 2, 2, 1]
+    assert all(r.stats["sharded"] and r.stats["shard_devices"] == 6
+               for r in rs)
+    assert all(r.stats["batch_bucket"] == 12 for r in rs)
+    assert all(r.stats["batch_bucket"] // 6 <= 2 for r in rs)
+    assert [r.stats["batch_size"] for r in rs] == [5] * 5
     _assert_same([stmt.execute(params=p) for p in plist], rs)
 
 
@@ -265,7 +275,9 @@ def test_mesh_capacity_chunking(db):
     assert sizes[: 2 * N_DEV] == [2 * N_DEV] * (2 * N_DEV)
     assert sizes[2 * N_DEV:] == [2, 2]
     assert rs[0].stats["sharded"]
-    assert rs[-1].stats["batch_bucket"] == 2
+    # the remainder chunk pads to the mesh rather than leaving it
+    assert rs[-1].stats["sharded"]
+    assert rs[-1].stats["batch_bucket"] == N_DEV
     _assert_same([stmt.execute(params=p) for p in params_list], rs)
 
 

@@ -184,3 +184,49 @@ def test_a_warm_call_and_a_warm_wave_record_at_most_six_spans(tmp_path):
     _ = t.result().masked
     jax.profiler.stop_trace()
     assert _froid_spans(tmp_path / "fetch") == ["froid.materialize"]
+
+
+def _dispatch_devices(trace_dir):
+    from jax.profiler import ProfileData
+
+    prof = ProfileData.from_file(
+        glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")[-1])
+    return [int(v) for p in prof.planes if p.name == "/host:CPU"
+            for line in p.lines for e in line.events
+            if e.name == "froid.dispatch"
+            for k, v in e.stats if k == "devices"]
+
+
+def test_a_wave_dispatch_names_its_devices(tmp_path):
+    """One device here; ``tests/test_placement.py`` reads 4 on a mesh."""
+    db = _db()
+    stmt = db.prepare(_q(), FROID)
+    ps = [{"p": float(k)} for k in range(3)]
+    stmt.execute_many(ps)
+    jax.profiler.start_trace(str(tmp_path))
+    stmt.execute_many(ps)
+    jax.profiler.stop_trace()
+    assert _dispatch_devices(tmp_path) == [1]
+
+
+@pytest.mark.parametrize("resilience", [True, False])
+def test_scheduler_and_session_count_where_waves_ran(resilience):
+    db = _db()
+    stmt = db.prepare(_q(), FROID)
+    sched = CoalescingScheduler(max_batch=8, window_s=10.0,
+                                clock=FakeClock(), resilience=resilience)
+    for k in range(3):   # one wave of 3 calls, bucket 4
+        sched.submit(stmt, {"p": float(k)})
+    assert sched.flush() == 3
+    assert sched.stats["batches"] == 1
+    assert {k: sched.stats[k] for k in (
+        "sharded_waves", "sharded_calls", "pad_calls")} == {
+        "sharded_waves": 0, "sharded_calls": 0, "pad_calls": 1}
+    assert {k: db.timing_stats[k] for k in (
+        "sharded_waves", "sharded_calls", "pad_calls")} == {
+        "sharded_waves": 0, "sharded_calls": 0, "pad_calls": 1}
+    for k in range(4):   # a full bucket adds no padding
+        sched.submit(stmt, {"p": float(k)})
+    sched.flush()
+    assert sched.stats["pad_calls"] == db.timing_stats["pad_calls"] == 1
+    assert sched.stats["batches"] == 2
