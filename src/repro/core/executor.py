@@ -7,9 +7,15 @@ Design (TPU adaptation of the paper's set-oriented plans, DESIGN.md §2):
   into the mask; no operator has a data-dependent output shape, so whole
   plans trace under ``jax.jit`` / ``vmap`` (which is how correlated Apply
   falls back to vectorized evaluation instead of a row loop).
-* **Joins** — sort + ``searchsorted`` (sort-merge) on the build side; the
-  build side must be key-unique (dimension semantics).  No hash tables: TPU
-  sorts are fast, random scatter is not.
+* **Joins** — each probe row takes the lowest-numbered live build row
+  with its key.  A join the optimizer annotated with a dense key range
+  (``Join.dense_range``: one integer build key over a stats range at most
+  4× its distinct count, on a build no parameter or outer row reaches)
+  addresses the build directly: a scatter-min of build row numbers into a
+  slot table over the range, one gather per probe row.  Every other join
+  (composite, float, sparse or computed keys, grouped or parameter-bound
+  builds) sorts the build side and ``searchsorted``s the probe keys.  No
+  hash tables.
 * **Group-by** — sort-based segmenting + ``jax.ops.segment_sum`` with a
   *static* group capacity (default: the row count), or the fused Pallas
   ``relagg`` kernel for the single-pass filter+project+aggregate hot path.
@@ -272,21 +278,10 @@ class Executor:
                        for n, c in right.table.columns.items()}),
                 jnp.zeros((1,), bool))
 
-        if len(node.on) == 1:
-            lcol, rcol = node.on[0]
-            lkeys = _sort_key_for(left.table.columns[lcol], left.mask)
-            rkeys = _sort_key_for(right.table.columns[rcol], right.mask)
-        else:
-            # composite keys: dense-rank the union of both sides' key
-            # tuples into one synthetic int32 key, then merge as usual
-            lkeys, rkeys = _union_dense_rank(left, right, node.on)
-        perm = jnp.argsort(rkeys, stable=True)
-        sorted_keys = jnp.take(rkeys, perm)
-
-        pos = jnp.searchsorted(sorted_keys, lkeys)
-        pos = jnp.clip(pos, 0, sorted_keys.shape[0] - 1)
-        hit = (jnp.take(sorted_keys, pos) == lkeys) & (lkeys != _key_sentinel(lkeys))
-        ridx = jnp.take(perm, pos)
+        probe = None
+        if node.dense_range is not None:
+            probe = _dense_probe(node, left, right)
+        ridx, hit = probe or _sorted_probe(node, left, right)
 
         if node.kind == "semi":
             return MaskedTable(left.table, left.mask & hit)
@@ -892,6 +887,52 @@ class Executor:
 
 def _key_sentinel(keys: jnp.ndarray):
     return _F32_MAX if jnp.issubdtype(keys.dtype, jnp.floating) else _I32_MAX
+
+
+def _sorted_probe(node: R.Join, left: MaskedTable, right: MaskedTable):
+    """``(build row, hit)`` per probe row by sort-merge: a stable argsort
+    of the build keys and a left ``searchsorted`` of the probe keys, so
+    among equal build keys the lowest-numbered row wins."""
+    if len(node.on) == 1:
+        lcol, rcol = node.on[0]
+        lkeys = _sort_key_for(left.table.columns[lcol], left.mask)
+        rkeys = _sort_key_for(right.table.columns[rcol], right.mask)
+    else:
+        # composite keys: dense-rank the union of both sides' key
+        # tuples into one synthetic int32 key, then merge as usual
+        lkeys, rkeys = _union_dense_rank(left, right, node.on)
+    perm = jnp.argsort(rkeys, stable=True)
+    sorted_keys = jnp.take(rkeys, perm)
+
+    pos = jnp.searchsorted(sorted_keys, lkeys)
+    pos = jnp.clip(pos, 0, sorted_keys.shape[0] - 1)
+    hit = (jnp.take(sorted_keys, pos) == lkeys) & (lkeys != _key_sentinel(lkeys))
+    return jnp.take(perm, pos), hit
+
+
+def _dense_probe(node: R.Join, left: MaskedTable, right: MaskedTable):
+    """``(build row, hit)`` per probe row by direct address over the key
+    range ``node.dense_range``: a scatter-min of build row numbers into one
+    slot per key value picks the lowest-numbered live row of each key, as
+    :func:`_sorted_probe` does.  None where a key column is not of an
+    integer type (the sorted probe then runs)."""
+    (lcol, rcol), = node.on
+    lk, rk = left.table.columns[lcol], right.table.columns[rcol]
+    if not (jnp.issubdtype(lk.dtype, jnp.integer)
+            and jnp.issubdtype(rk.dtype, jnp.integer)):
+        return None
+    lo, hi = node.dense_range
+    span, nr = hi - lo + 1, right.num_rows
+    # compared before subtracting, so no key outside [lo, hi] can wrap in
+    rkey = rk.data.astype(jnp.int32)
+    rlive = right.mask & rk.validity() & (rkey >= lo) & (rkey <= hi)
+    slot = jnp.full((span,), nr, jnp.int32).at[
+        jnp.where(rlive, rkey - lo, span)].min(
+        jnp.arange(nr, dtype=jnp.int32), mode="drop")
+    lkey = lk.data.astype(jnp.int32)
+    inside = left.mask & lk.validity() & (lkey >= lo) & (lkey <= hi)
+    ridx = jnp.take(slot, jnp.where(inside, lkey - lo, 0), mode="clip")
+    return ridx, inside & (ridx < nr)
 
 
 def _full_agg(fn: str, v: S.Value | None, mask: jnp.ndarray) -> Column:

@@ -537,7 +537,8 @@ def prune_columns(plan: R.RelNode, catalog=None, required: set[str] | None = Non
             lreq = None if req is None else (req | lk)
             rreq = None if req is None else (req | rk)
             return R.Join(
-                rec(node.left, lreq), rec(node.right, rreq), node.on, node.kind
+                rec(node.left, lreq), rec(node.right, rreq), node.on, node.kind,
+                node.dense_range,
             )
         if isinstance(node, R.Apply):
             # conservative: right side's outer refs must stay available
@@ -1129,42 +1130,97 @@ def pinned_groupaggs(plan: R.RelNode) -> int:
 # driver
 # ---------------------------------------------------------------------------
 
+#: the widest key range a direct-address join's slot table may span:
+#: 2**26 int32 slots (256 MiB), room for TPC-H SF 10's 60M order keys
+DENSE_JOIN_MAX_SPAN = 1 << 26
+
+
+def _source_stats(node: R.RelNode, col: str, catalog):
+    """The base table's ``(distinct, min, max)`` stats of ``col``, where it
+    traces through Filter, Compute and Project renames to a Scan with the
+    values untouched; else None."""
+    while isinstance(node, (R.Filter, R.Compute, R.Project)):
+        if isinstance(node, R.Compute) and col in node.computed:
+            return None
+        if isinstance(node, R.Project):
+            if col not in node.cols:
+                return None
+            col = node.cols[col]
+        node = node.child
+    if isinstance(node, R.Scan):
+        t = catalog.get(node.table)
+        if t is not None and col in getattr(t, "stats", {}):
+            return t.stats[col]
+    return None
+
+
+def _binds_per_execution(plan: R.RelNode) -> bool:
+    """True where ``plan`` reads a parameter, an outer row or a variable:
+    it is then evaluated once per binding (a wave's vmap, a per-row
+    apply), not once per program."""
+    return any(isinstance(x, (S.Param, S.Outer, S.Var))
+               for n in R.walk_plan(plan) for e in n.exprs()
+               for x in S.walk(e))
+
+
+def _dense_join_range(node: R.Join, catalog) -> tuple[int, int] | None:
+    """The build key range a direct-address lowering of ``node`` indexes:
+    one key pair, the build key an integer or dictionary base column whose
+    stats range spans at most 4× its distinct values and at most
+    :data:`DENSE_JOIN_MAX_SPAN`, on a build that is one slot table per
+    program (:func:`_binds_per_execution`)."""
+    if len(node.on) != 1:
+        return None
+    st = _source_stats(node.right, node.on[0][1], catalog)
+    if st is None:
+        return None
+    distinct, lo, hi = st
+    span = hi - lo + 1
+    # the sorted probe never matches the int32 sentinel; neither may this
+    if (not 0 < span <= min(4 * distinct, DENSE_JOIN_MAX_SPAN)
+            or hi >= np.iinfo(np.int32).max
+            or _binds_per_execution(node.right)):
+        return None
+    return lo, hi
+
+
+def dense_joins(plan: R.RelNode) -> int:
+    """How many joins of ``plan`` :func:`annotate_group_stats` gave a dense
+    key range, subquery plans included."""
+    return sum(isinstance(n, R.Join) and n.dense_range is not None
+               for n in R.walk_plan_deep(plan))
+
+
 def annotate_group_stats(plan: R.RelNode, catalog=None):
-    """§Perf (Froid engine): statistics-driven group-by planning.
+    """§Perf (Froid engine): statistics-driven group-by and join planning.
 
     For a single-int-key GroupAgg whose key column traces to a base-table
     scan through Filter/Compute (key untouched), attach the table's
     (distinct, min, max) stats: ``capacity`` bounds the segment arrays and
     a dense key range switches the executor to direct ``gid = key - lo``
-    segmenting — no sort.  This is what a cost-based optimizer gets from
+    segmenting — no sort.  A join whose build key qualifies
+    (:func:`_dense_join_range`) gets the range as ``dense_range`` and is
+    probed by direct address — no sort, no search; a join that no longer
+    qualifies loses it.  This is what a cost-based optimizer gets from
     histograms; UDFs used to hide it (paper §2.3 'lack of costing')."""
     if not catalog:
         return plan, False
     changed = [False]
 
-    def source_stats(node: R.RelNode, col: str):
-        while isinstance(node, (R.Filter, R.Compute, R.Project)):
-            if isinstance(node, R.Compute) and col in node.computed:
-                return None
-            if isinstance(node, R.Project):
-                if col not in node.cols:
-                    return None
-                col = node.cols[col]
-            node = node.child
-        if isinstance(node, R.Scan):
-            t = catalog.get(node.table)
-            if t is not None and col in getattr(t, "stats", {}):
-                return t.stats[col]
-        return None
-
     def rule(node: R.RelNode):
+        if isinstance(node, R.Join):
+            want = _dense_join_range(node, catalog)
+            if want == node.dense_range:
+                return None
+            changed[0] = True
+            return R.Join(node.left, node.right, node.on, node.kind, want)
         if (
             not isinstance(node, R.GroupAgg)
             or len(node.keys) != 1
             or node.dense_range is not None
         ):
             return None
-        st = source_stats(node.child, node.keys[0])
+        st = _source_stats(node.child, node.keys[0], catalog)
         if st is None:
             return None
         distinct, lo, hi = st
@@ -1314,7 +1370,8 @@ def explain(plan: R.RelNode, indent: int = 0) -> str:
         out.append(f"{pad}Filter {n.pred!r}")
         out.append(explain(n.child, indent + 1))
     elif isinstance(n, R.Join):
-        out.append(f"{pad}Join[{n.kind}] on {n.on}")
+        dense = "" if n.dense_range is None else f" dense={n.dense_range}"
+        out.append(f"{pad}Join[{n.kind}] on {n.on}{dense}")
         out.append(explain(n.left, indent + 1))
         out.append(explain(n.right, indent + 1))
     elif isinstance(n, R.Apply):

@@ -121,10 +121,18 @@ class Filter(RelNode):
 class Join(RelNode):
     """Equi-join on key column pairs.  ``kind`` in inner|left|semi|anti.
 
-    The build (right) side must be key-unique for inner/left joins — the
-    engine verifies this at execution.  Lowered to a dense-key gather when
-    the build keys form a dense integer range (FK join), else to
-    sort + searchsorted (sort-merge; TPU-friendly, no hash tables).
+    Each probe (left) row matches at most one build (right) row: the
+    lowest-numbered live build row with its key, so inner/left joins keep
+    dimension semantics only where the build side is key-unique.  Two
+    lowerings, one answer:
+
+    * ``dense_range`` set (the optimizer's stats pass, for a single integer
+      build key that a base table's stats bound to a dense ``[lo, hi]``,
+      on a build that no parameter or outer row reaches): direct address —
+      a slot table over ``[lo, hi]`` holds each key's build row, and the
+      probe reads ``slot[key - lo]``.  No sort, no search.
+    * otherwise: sort + searchsorted (sort-merge; composite keys
+      dense-ranked into one; TPU-friendly, no hash tables).
     """
 
     def __init__(
@@ -133,16 +141,18 @@ class Join(RelNode):
         right: RelNode,
         on: Sequence[tuple[str, str]],
         kind: str = "inner",
+        dense_range: tuple[int, int] | None = None,
     ):
         super().__init__()
         assert kind in ("inner", "left", "semi", "anti"), kind
         self.left, self.right, self.on, self.kind = left, right, list(on), kind
+        self.dense_range = dense_range
 
     def children(self):
         return [self.left, self.right]
 
     def with_children(self, kids):
-        return Join(kids[0], kids[1], self.on, self.kind)
+        return Join(kids[0], kids[1], self.on, self.kind, self.dense_range)
 
     def __repr__(self):
         return f"Join[{self.kind}]({self.left!r}, {self.right!r}, on={self.on})"

@@ -762,11 +762,13 @@ class Session:
         # call that compiles holds the compile in its args_s (AOT or store
         # load) or dispatch_s (jit); repro.telemetry counts compiles apart.
         # Per plan that prepare builds, the GroupAggs the optimizer
-        # collapsed on pinned keys.  Per batched or fused program run, its
-        # placement: programs run over a mesh and the calls they answered,
-        # and the padding rows its bucket added (power-of-two and mesh)
+        # collapsed on pinned keys and the joins it gave a dense key range.
+        # Per batched or fused program run, its placement: programs run
+        # over a mesh and the calls they answered, and the padding rows its
+        # bucket added (power-of-two and mesh)
         self.timing_stats = {
             "tables": 0, "catalog_s": 0.0, "pinned_groupaggs": 0,
+            "dense_joins": 0,
             "executions": 0, "args_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0,
             "materializations": 0, "materialize_s": 0.0,
             "sharded_waves": 0, "sharded_calls": 0, "pad_calls": 0,
@@ -1083,7 +1085,8 @@ class Session:
             plan = O.optimize(
                 plan, self.catalog, required=set(wanted) if wanted else None
             )
-            self._timed(pinned_groupaggs=O.pinned_groupaggs(plan))
+            self._timed(pinned_groupaggs=O.pinned_groupaggs(plan),
+                        dense_joins=O.dense_joins(plan))
         if wanted is not None:
             try:
                 have = R.output_columns(plan, self.catalog)

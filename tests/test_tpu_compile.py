@@ -84,3 +84,25 @@ def test_q6_plan_compiles_for_v5e(one_chip, no_compile_cache):
     li = db.catalog["lineitem"]
     assert mem.argument_size_in_bytes >= li.columns["l_extendedprice"].data.nbytes
     assert "revenue" in entry.out_dicts
+
+
+def test_q12_plan_compiles_for_v5e_without_a_search_loop(one_chip,
+                                                          no_compile_cache):
+    """Q12's lineitem → orders join builds on a dense order key: its
+    compiled program probes by direct address, so no binary-search
+    ``while`` loop (``searchsorted``'s lowering) is left in it."""
+    from benchmarks.tpch_udfs import q12_udf, register_udfs
+    from repro.core import FROID, Session
+    from repro.data.tpch import generate_tpch
+
+    db = Session()
+    generate_tpch(db, sf=0.1)
+    register_udfs(db)
+    stmt = db.prepare(q12_udf(), FROID)
+    assert db.timing_stats["dense_joins"] == 1, stmt.explain()
+    entry, _, _ = db._executable(stmt.node, stmt._query_fp, stmt.policy, None)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        db._catalog_args())
+    hlo = jax.jit(entry.raw).lower(args, {}).compile().as_text()
+    assert " while(" not in hlo
