@@ -3,7 +3,7 @@
 # pyproject.toml sets pythonpath=src, so no PYTHONPATH export is needed for
 # pytest — this script exists so `scripts/verify.sh` is the one canonical
 # spelling (extra pytest args pass through, e.g.
-# `scripts/verify.sh -m "not slow"`).
+# `scripts/verify.sh -k relagg`).
 #
 # VERIFY_BENCH=0 skips the perf smoke (tests only).
 set -euo pipefail

@@ -4,11 +4,11 @@ The estimator walks a bound plan bottom-up carrying textbook cardinality
 rules (catalog row counts at the leaves, fixed selectivities at the
 predicates) and accumulates a roofline-style work profile — scalar flops
 and bytes touched — which :class:`PlanProfile.seconds` turns into a time
-estimate using the hardware constants from ``repro.launch.roofline``
-(``PEAK_FLOPS`` / ``HBM_BW``).  Absolute numbers are nominal for the
-accelerator target, not this host; the router only ever compares
-estimates against each other (and hands control to measured wave costs as
-soon as they exist), so the *ratios* are what matter:
+estimate using one TPU v5e chip's peaks (``PEAK_FLOPS`` / ``HBM_BW``).
+Absolute numbers are nominal for the accelerator target, not the host
+running the estimate; the router only ever compares estimates against
+each other (and hands control to measured wave costs as soon as they
+exist), so the *ratios* are what matter:
 
 * a per-row interpreted UDF call costs a large per-row penalty relative
   to inlined arithmetic — the FROID-vs-HEKATON axis;
@@ -29,7 +29,11 @@ import dataclasses
 from repro.core import relalg as R
 from repro.core import scalar as S
 from repro.core.executor import _plan_outer_refs
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+
+#: one TPU v5e chip's bf16 FLOP/s and HBM bytes/s (Google Cloud
+#: documentation, "TPU v5e"; the figures ``bench/peaks.py`` cites)
+PEAK_FLOPS = 197e12
+HBM_BW = 819e9
 
 #: fixed launch cost of one device program dispatch (host → runtime →
 #: device round trip); the term fusion amortizes

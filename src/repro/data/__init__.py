@@ -1,4 +1,3 @@
-from repro.data.pipeline import DataPipeline, synthetic_corpus
 from repro.data.tpch import generate_tpch
 
-__all__ = ["DataPipeline", "synthetic_corpus", "generate_tpch"]
+__all__ = ["generate_tpch"]

@@ -1,35 +1,11 @@
-# Distribution layer: activation sharding constraints, parameter/batch/
-# cache sharding rules for the production meshes, and the int8
-# error-feedback gradient compression used on the cross-pod reduction.
-from repro.dist.activations import (
-    clear_activation_mesh,
-    current_activation_mesh,
-    set_activation_mesh,
-    shard_batch,
-)
-from repro.dist.compress import (
-    compress_tree,
-    decompress_tree,
-    dequantize_int8,
-    ef_quantize,
-    init_error_tree,
-    quantize_int8,
-)
+# Data-axis placement for the engine's sharded execute_many waves: the
+# batch axis over the mesh's data axes, the catalog replicated.
 from repro.dist.sharding import (
     batch_sharding,
-    batch_specs,
-    cache_specs,
     data_axis_size,
-    param_specs,
     pick_data_axes,
     replicated_sharding,
-    shardings_for,
 )
 
-__all__ = [
-    "set_activation_mesh", "clear_activation_mesh", "current_activation_mesh",
-    "shard_batch", "param_specs", "batch_specs", "cache_specs",
-    "shardings_for", "pick_data_axes", "data_axis_size", "batch_sharding",
-    "replicated_sharding", "compress_tree", "decompress_tree",
-    "init_error_tree", "quantize_int8", "dequantize_int8", "ef_quantize",
-]
+__all__ = ["pick_data_axes", "data_axis_size", "batch_sharding",
+           "replicated_sharding"]

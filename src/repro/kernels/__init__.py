@@ -4,9 +4,6 @@
 #   ops.py     — the jit'd public wrapper (auto-interpret on CPU)
 #   ref.py     — pure-jnp oracle used by the allclose test sweeps
 #
-# relagg          — fused filter+project+group-aggregate (the paper's
-#                   set-oriented plan hot loop, batch-mode §8.2.6, as
-#                   one-hot × MXU matmul partial aggregation)
-# flash_attention — blockwise online-softmax attention (causal / sliding
-#                   window / GQA) for the assigned LM architectures
-# ssd_scan        — Mamba-2 state-space-duality chunked scan
+# relagg — fused filter+project+group-aggregate (the paper's
+#          set-oriented plan hot loop, batch-mode §8.2.6, as
+#          one-hot × MXU matmul partial aggregation)

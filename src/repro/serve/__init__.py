@@ -1,7 +1,6 @@
-from repro.serve.engine import Request, ServeEngine
 from repro.serve.admission import AdmissionPolicy
 from repro.serve.fleet import FleetEngine, FleetWorker
 from repro.serve.scheduler import CoalescingScheduler, Ticket
 
-__all__ = ["Request", "ServeEngine", "AdmissionPolicy", "FleetEngine",
-           "FleetWorker", "CoalescingScheduler", "Ticket"]
+__all__ = ["AdmissionPolicy", "FleetEngine", "FleetWorker",
+           "CoalescingScheduler", "Ticket"]

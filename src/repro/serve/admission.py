@@ -166,8 +166,7 @@ class AdmissionPolicy:
         # the request session) drain as one fused device program; adaptive:
         # the flush window tracks the observed arrival rate; timeout_s:
         # default per-ticket deadline (expired tickets shed with a typed
-        # DeadlineExceeded instead of executing — the engine maps that to
-        # a "shed" completion)
+        # DeadlineExceeded instead of executing)
         self.timeout_s = timeout_s
         self.scheduler = scheduler or CoalescingScheduler(
             fuse=fuse, adaptive=adaptive, default_timeout_s=timeout_s,

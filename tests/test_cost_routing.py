@@ -93,6 +93,23 @@ def test_estimates_scale_with_work():
     assert estimate_compile_s(plan) > estimate_compile_s(small) > 0
 
 
+def test_cost_model_peaks_are_the_benchmarks_v5e_peaks():
+    """The engine keeps its own copy of the two peaks (the engine imports
+    nothing of the benchmark); they must equal the benchmark's table."""
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.peaks import peaks
+    from repro.cost import model
+
+    v5e = peaks("TPU v5 lite")
+    assert model.PEAK_FLOPS == v5e["bf16_flops_per_s"]
+    assert model.HBM_BW == v5e["hbm_bytes_per_s"]
+
+
 # ---------------------------------------------------------------------------
 # sample intake: EMA updates and fault-window exclusion
 # ---------------------------------------------------------------------------

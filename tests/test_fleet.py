@@ -225,7 +225,7 @@ def test_fleet_broadcast_returns_worker_order(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# admission-path persistence (ServeEngine pass-through)
+# admission-path persistence
 # ---------------------------------------------------------------------------
 
 

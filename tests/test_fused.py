@@ -473,16 +473,3 @@ def test_admission_policy_fuse_adaptive_passthrough():
     co = ap.evaluate_coalesced(reqs)
     np.testing.assert_array_equal(tick["admit"], co["admit"])
     np.testing.assert_array_equal(tick["granted"], co["granted"])
-
-
-def test_serve_engine_fuse_passthrough():
-    from repro.serve.engine import ServeEngine
-
-    class _Model:
-        def decode_step(self, params, cache, tok):  # never invoked here
-            return None, cache
-
-    eng = ServeEngine(_Model(), params={}, admission_fuse=True,
-                      admission_adaptive=True)
-    assert eng.admission.scheduler.fuse
-    assert eng.admission.scheduler.adaptive

@@ -587,41 +587,3 @@ def test_chaos_deadline_under_faults():
     for kind, v in out["outcomes"]:
         if kind == "error":
             assert isinstance(v, ResilienceError)
-
-
-# ---------------------------------------------------------------------------
-# serving engine: shed completions instead of crashed drains
-# ---------------------------------------------------------------------------
-
-
-def test_serve_engine_drain_sheds_expired_admission():
-    import jax
-
-    from repro.configs import smoke_config_for
-    from repro.models import build_model
-    from repro.serve.engine import Request, ServeEngine
-
-    class Step:  # every clock() call advances 1s: tickets expire between
-        def __init__(self):  # submit and drain
-            self.now = 0.0
-
-        def __call__(self):
-            self.now += 1.0
-            return self.now
-
-    cfg = smoke_config_for("granite3_2b")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    sched = _sched(clock=Step(), default_timeout_s=0.5)
-    eng = ServeEngine(model, params, slots=2, max_len=64,
-                      admission_scheduler=sched)
-    rng = np.random.default_rng(0)
-    for i in range(3):
-        eng.submit(Request(
-            rid=i, prompt=rng.integers(0, cfg.vocab, 8).astype(np.int32),
-            max_new_tokens=4))
-    done = eng.drain()
-    assert len(done) == 3
-    assert all(c.reason == "shed" and c.tokens == [] for c in done)
-    assert len(eng.shed) == 3
-    assert sched.stats["deadline_shed"] == 3

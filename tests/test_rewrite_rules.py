@@ -1,49 +1,12 @@
-"""Roofline HLO parsing + optimizer rewrite-rule semantics preservation."""
+"""Optimizer rewrite rules preserve semantics."""
 import numpy as np
-import pytest
 
 from repro.core import Database, col, count_, lit, scan, sum_
 from repro.core import optimizer as O
 from repro.core import relalg as R
 from repro.core import scalar as S
-from repro.launch.roofline import Roofline, parse_collectives
 
 
-HLO = """
-HloModule test
-ENTRY %main {
-  %p0 = f32[128,256]{1,0} parameter(0)
-  %ag = f32[2048,256]{1,0} all-gather(%p0), dimensions={0}
-  %ar = f32[2048,256]{1,0} all-reduce(%ag), to_apply=%add
-  %rs = f32[128,256]{1,0} reduce-scatter(%ar), dimensions={0}
-  %cp = f32[128,256]{1,0} collective-permute(%rs), source_target_pairs={{0,1}}
-  ROOT %out = f32[128,256]{1,0} add(%cp, %p0)
-}
-"""
-
-
-def test_parse_collectives_operand_bytes():
-    stats = parse_collectives(HLO)
-    assert stats.count_by_kind["all-gather"] == 1
-    assert stats.count_by_kind["all-reduce"] == 1
-    assert stats.count_by_kind["reduce-scatter"] == 1
-    assert stats.count_by_kind["collective-permute"] == 1
-    # all-gather operand = p0 = 128*256*4
-    assert stats.bytes_by_kind["all-gather"] == 128 * 256 * 4
-    # all-reduce operand = ag = 2048*256*4
-    assert stats.bytes_by_kind["all-reduce"] == 2048 * 256 * 4
-
-
-def test_roofline_terms_and_dominant():
-    r = Roofline(flops=197e12, hbm_bytes=819e9 * 2, collective_bytes=50e9,
-                 chips=256)
-    assert r.t_compute == pytest.approx(1.0)
-    assert r.t_memory == pytest.approx(2.0)
-    assert r.t_collective == pytest.approx(1.0)
-    assert r.dominant == "memory"
-
-
-# ---------------------------------------------------------------- rules
 def _db(rng):
     db = Database()
     db.create_table(

@@ -370,18 +370,3 @@ def test_admission_sharded_matches_tick_path():
     np.testing.assert_allclose(tick["temp"], co["temp"], rtol=1e-6)
     if N_DEV > 1:
         assert ap.request_statement().policy.shard_devices() == N_DEV
-
-
-@multi_device
-def test_serve_engine_accepts_admission_mesh():
-    """ServeEngine wires admission_mesh through to the sharded per-request
-    admission statement (full decode loop covered by test_serve_and_data)."""
-    from repro.serve.engine import ServeEngine
-
-    class _NoModel:
-        def decode_step(self, params, cache, tok):  # pragma: no cover
-            raise AssertionError("decode never reached in this test")
-
-    eng = ServeEngine(_NoModel(), params=None, admission_mesh=_mesh())
-    assert eng.admission.mesh is not None
-    assert eng.admission.request_statement().policy.shard_devices() == N_DEV
