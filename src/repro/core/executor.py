@@ -16,9 +16,19 @@ Design (TPU adaptation of the paper's set-oriented plans, DESIGN.md §2):
   (composite, float, sparse or computed keys, grouped or parameter-bound
   builds) sorts the build side and ``searchsorted``s the probe keys.  No
   hash tables.
-* **Group-by** — sort-based segmenting + ``jax.ops.segment_sum`` with a
-  *static* group capacity (default: the row count), or the fused Pallas
-  ``relagg`` kernel for the single-pass filter+project+aggregate hot path.
+* **Group-by** — two lowerings.  A GroupAgg the optimizer annotated with
+  ``GroupAgg.dense_range`` (every key an integer or dictionary base column
+  with no NULL, read untouched through filters, renames and inner joins,
+  with catalog stats bounding it: a small product of spans, or one key
+  over at most 4× its distinct values) segments by direct address — a
+  row's slot is the mixed-radix code of its keys, one output slot per
+  code.  Up to :data:`SMALL_GROUP_DOMAIN` slots each aggregate is one
+  masked reduction per slot; past it, ``segment_*`` scatters.  Every other
+  GroupAgg (computed, float, NULL-able or wide composite keys, keys read
+  through outer joins) sorts: argsort by the keys, gather every key and
+  input by the permutation, ``segment_*`` over a static capacity (the
+  stats' distinct count for one traced key, else the row count).  The
+  Pallas ``relagg`` kernel is an opt-in third (``pallas_agg``).
 * **CSE for free** — node results are memoized per execution, which is the
   relational version of common-subexpression elimination (paper §6).
 * **Named operators** — each node runs under ``jax.named_scope`` of its
@@ -30,6 +40,7 @@ Design (TPU adaptation of the paper's set-oriented plans, DESIGN.md §2):
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable
 
 import jax
@@ -91,6 +102,34 @@ def _sort_key_for(col: Column, mask: jnp.ndarray) -> jnp.ndarray:
     if jnp.issubdtype(data.dtype, jnp.floating):
         return jnp.where(ok, data, _F32_MAX)
     return jnp.where(ok, data.astype(jnp.int32), _I32_MAX)
+
+
+#: the most slots a direct-address GroupAgg reduces as one masked
+#: reduction per slot; past it, ``segment_*`` scatters.  Set from a v5e
+#: microbenchmark (``scripts/groupagg_microbench.py``, PERF.md §6): 6M
+#: float32 rows into G groups.  The reduction's cost grows with G and the
+#: scatter's does not; at 256 slots eight inputs cost 2.4 % of the
+#: scatter's time, at 4,096 still 46 %.  XLA's CPU backend materializes
+#: the (G, rows) comparison, which this also bounds.  The optimizer takes
+#: composite keys by direct address only up to this many slots.
+SMALL_GROUP_DOMAIN = 256
+
+
+def _slot_reducer(gid: jnp.ndarray, G: int):
+    """``reduce(kind, x)``: ``x`` reduced (kind sum, min or max) per slot
+    ``0 .. G-1`` of ``gid``; rows in slot G count nowhere.  Up to
+    :data:`SMALL_GROUP_DOMAIN` slots, each slot compares ``gid`` and selects
+    (one fused pass over ``x``; float32 reductions, not a one-hot matmul,
+    which the MXU would round to bfloat16); past it, a ``segment_*``
+    scatter."""
+    if G <= SMALL_GROUP_DOMAIN:
+        hit = jnp.arange(G, dtype=jnp.int32)[:, None] == gid[None, :]
+        fill = {"sum": 0.0, "min": jnp.inf, "max": -jnp.inf}
+        return lambda kind, x: getattr(jnp, kind)(
+            jnp.where(hit, x[None, :], fill[kind]), axis=1)
+    seg = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+           "max": jax.ops.segment_max}
+    return lambda kind, x: seg[kind](x, gid, num_segments=G + 1)[:G]
 
 
 def _union_dense_rank(left: "MaskedTable", right: "MaskedTable", on):
@@ -475,9 +514,9 @@ class Executor:
             if out is not None:
                 return out
 
-        # stats-driven dense-key path (§Perf hillclimb 3): key densely
-        # covers [lo, hi] -> gid = key - lo segmenting, NO sort
-        if node.dense_range is not None and len(node.keys) == 1:
+        # stats-driven direct address: every key within its (lo, hi) ->
+        # slot = the keys' mixed-radix code, NO sort, NO gather
+        if node.dense_range is not None:
             out = self._dense_groupagg(node, child, agg_inputs)
             if out is not None:
                 return out
@@ -568,44 +607,50 @@ class Executor:
         return MaskedTable(Table(cols), occupied)
 
     def _dense_groupagg(self, node: R.GroupAgg, child: MaskedTable, agg_inputs):
-        """Sort-free grouped aggregation for a dense int key range
-        [lo, hi]: gid = key - lo, segment ops sized to the range."""
-        key = node.keys[0]
-        kc = child.table.columns[key]
-        if not jnp.issubdtype(kc.dtype, jnp.integer):
+        """Sort-free grouped aggregation by direct address.  Each key lies
+        in its ``node.dense_range`` pair; a row's slot is the mixed-radix
+        code of its keys, the first key most significant, so occupied
+        slots ascend as the sort path's groups do.  Rows masked out, with a
+        NULL key or outside a range go to the overflow slot G.  Every output
+        key is decoded from the slot number: nothing is sorted or gathered.
+        None where a key column is not integer-typed (the sort path runs)."""
+        kcols = [child.table.columns[k] for k in node.keys]
+        if not all(jnp.issubdtype(c.dtype, jnp.integer) for c in kcols):
             return None
-        lo, hi = node.dense_range
-        cap = hi - lo + 1
-        n = child.num_rows
-        gid = kc.data.astype(jnp.int32) - lo
-        inside = (gid >= 0) & (gid < cap) & child.mask & kc.validity()
-        gid = jnp.where(inside, gid, cap)  # overflow slot
+        spans = [hi - lo + 1 for lo, hi in node.dense_range]
+        G = math.prod(spans)
+        inside = child.mask
+        gid = jnp.zeros((child.num_rows,), jnp.int32)
+        for c, (lo, hi), span in zip(kcols, node.dense_range, spans):
+            k = c.data.astype(jnp.int32)
+            # compared before subtracting, so no key outside can wrap in
+            ok = (k >= lo) & (k <= hi)
+            inside = inside & ok & c.validity()
+            gid = gid * span + jnp.where(ok, k - lo, 0)
+        gid = jnp.where(inside, gid, G)
+        reduce = _slot_reducer(gid, G)
 
-        cols: dict[str, Column] = {}
-        cnt_rows = jax.ops.segment_sum(
-            inside.astype(jnp.float32), gid, num_segments=cap + 1
-        )[:cap]
+        cnt_rows = reduce("sum", inside.astype(jnp.float32))
         occupied = cnt_rows > 0
-        cols[key] = Column(
-            (jnp.arange(cap, dtype=jnp.int32) + lo).astype(kc.data.dtype),
-            occupied,
-            kc.dictionary,
-        )
+        cols: dict[str, Column] = {}
+        slot = jnp.arange(G, dtype=jnp.int32)
+        stride = G
+        for name, c, (lo, _), span in zip(node.keys, kcols, node.dense_range,
+                                          spans):
+            stride //= span
+            cols[name] = Column((slot // stride % span + lo).astype(c.dtype),
+                                occupied, c.dictionary)
         for name, spec in node.aggs.items():
             if spec.fn == "count_star":
                 cols[name] = Column(cnt_rows.astype(jnp.int32), occupied)
                 continue
             v = agg_inputs[name]
             vvalid = v.validity() & inside
-            data = v.data
+            data = v.data.astype(jnp.float32)
+            c = (cnt_rows if v.valid is None
+                 else reduce("sum", jnp.where(vvalid, 1.0, 0.0)))
             if spec.fn in ("sum", "avg", "count"):
-                s = jax.ops.segment_sum(
-                    jnp.where(vvalid, data.astype(jnp.float32), 0.0),
-                    gid, num_segments=cap + 1,
-                )[:cap]
-                c = jax.ops.segment_sum(
-                    jnp.where(vvalid, 1.0, 0.0), gid, num_segments=cap + 1
-                )[:cap]
+                s = reduce("sum", jnp.where(vvalid, data, 0.0))
                 if spec.fn == "sum":
                     cols[name] = Column(s, occupied & (c > 0))
                 elif spec.fn == "count":
@@ -615,16 +660,9 @@ class Executor:
                         s / jnp.where(c == 0, 1.0, c), occupied & (c > 0)
                     )
             elif spec.fn in ("min", "max"):
-                seg = jax.ops.segment_min if spec.fn == "min" else jax.ops.segment_max
                 sent = jnp.inf if spec.fn == "min" else -jnp.inf
-                m = seg(
-                    jnp.where(vvalid, data.astype(jnp.float32), sent),
-                    gid, num_segments=cap + 1,
-                )[:cap]
-                any_v = jax.ops.segment_sum(
-                    jnp.where(vvalid, 1.0, 0.0), gid, num_segments=cap + 1
-                )[:cap] > 0
-                cols[name] = Column(m, occupied & any_v)
+                m = reduce(spec.fn, jnp.where(vvalid, data, sent))
+                cols[name] = Column(m, occupied & (c > 0))
             else:
                 return None
         return MaskedTable(Table(cols), occupied)

@@ -25,16 +25,21 @@ Rules (each is a semantics-preserving plan rewrite, unit-tested):
 * ``collapse_pinned_groupaggs`` — a GroupAgg whose every key is pinned has
                             one group at most: a keyless aggregate, its
                             keys set to the pinned values
+* ``annotate_group_stats`` — catalog stats turn GroupAggs over small key
+                            domains and joins on dense build keys into
+                            direct-address lowerings (no sort)
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import relalg as R
 from repro.core import scalar as S
+from repro.core.executor import SMALL_GROUP_DOMAIN
 from repro.core.fingerprint import _norm as _fp_norm
 from repro.core.fingerprint import plan_fingerprint
 
@@ -1135,23 +1140,51 @@ def pinned_groupaggs(plan: R.RelNode) -> int:
 DENSE_JOIN_MAX_SPAN = 1 << 26
 
 
-def _source_stats(node: R.RelNode, col: str, catalog):
-    """The base table's ``(distinct, min, max)`` stats of ``col``, where it
-    traces through Filter, Compute and Project renames to a Scan with the
-    values untouched; else None."""
-    while isinstance(node, (R.Filter, R.Compute, R.Project)):
-        if isinstance(node, R.Compute) and col in node.computed:
-            return None
-        if isinstance(node, R.Project):
-            if col not in node.cols:
-                return None
-            col = node.cols[col]
-        node = node.child
-    if isinstance(node, R.Scan):
-        t = catalog.get(node.table)
-        if t is not None and col in getattr(t, "stats", {}):
-            return t.stats[col]
+def _source_column(node: R.RelNode, col: str, catalog):
+    """The base ``(table, column)`` whose values ``col`` carries, where it
+    traces through Filter, Compute and Project renames, and into the side
+    of an inner Join that produces it, to a Scan with the values untouched;
+    else None.  Each step keeps base values on every live row (an inner
+    join copies both sides' values unchanged), so the base column's stats
+    bound them.  Left, semi and anti joins stop the trace: a left join's
+    build columns can come out NULL."""
+    while True:
+        if isinstance(node, R.Compute) and col not in node.computed:
+            node = node.child
+        elif isinstance(node, R.Filter):
+            node = node.child
+        elif isinstance(node, R.Project) and col in node.cols:
+            col, node = node.cols[col], node.child
+        elif isinstance(node, R.Join) and node.kind == "inner":
+            node = _join_side(node, col, catalog)
+        else:
+            break
+    if isinstance(node, R.Scan) and catalog.get(node.table) is not None:
+        return catalog[node.table], col
     return None
+
+
+def _join_side(node: R.Join, col: str, catalog) -> R.RelNode | None:
+    """The input of ``node`` whose column ``col`` its output carries: the
+    left one where only it has ``col`` or both share it as a key; the
+    right one where only it has it; else None."""
+    try:
+        left = R.output_columns(node.left, catalog)
+        right = R.output_columns(node.right, catalog)
+    except (KeyError, TypeError):
+        return None
+    if col in left and (col not in right or (col, col) in node.on):
+        return node.left
+    if col in right and col not in left:
+        return node.right
+    return None
+
+
+def _source_stats(node: R.RelNode, col: str, catalog):
+    """The base table's ``(distinct, min, max)`` stats of ``col``
+    (:func:`_source_column`); else None."""
+    src = _source_column(node, col, catalog)
+    return None if src is None else getattr(src[0], "stats", {}).get(src[1])
 
 
 def _binds_per_execution(plan: R.RelNode) -> bool:
@@ -1191,14 +1224,41 @@ def dense_joins(plan: R.RelNode) -> int:
                for n in R.walk_plan_deep(plan))
 
 
+def dense_groupaggs(plan: R.RelNode) -> int:
+    """How many GroupAggs of ``plan`` :func:`annotate_group_stats` gave
+    key ranges (direct address), subquery plans included."""
+    return sum(isinstance(n, R.GroupAgg) and n.dense_range is not None
+               for n in R.walk_plan_deep(plan))
+
+
+def _key_stats(node: R.GroupAgg, catalog) -> list[tuple[int, int, int]] | None:
+    """Each key's base ``(distinct, min, max)`` stats, where every key
+    traces (:func:`_source_column`) to an integer or dictionary base column
+    that holds no NULL; else None.  A NULL key would need a group of its
+    own, which only the sort path makes."""
+    out = []
+    for k in node.keys:
+        src = _source_column(node.child, k, catalog)
+        if src is None or src[1] in getattr(src[0], "nullable", ()):
+            return None
+        st = getattr(src[0], "stats", {}).get(src[1])
+        if st is None:
+            return None
+        out.append(st)
+    return out
+
+
 def annotate_group_stats(plan: R.RelNode, catalog=None):
     """§Perf (Froid engine): statistics-driven group-by and join planning.
 
-    For a single-int-key GroupAgg whose key column traces to a base-table
-    scan through Filter/Compute (key untouched), attach the table's
-    (distinct, min, max) stats: ``capacity`` bounds the segment arrays and
-    a dense key range switches the executor to direct ``gid = key - lo``
-    segmenting — no sort.  A join whose build key qualifies
+    A GroupAgg whose every key traces to an integer or dictionary base
+    column with stats and no NULL (:func:`_key_stats`) gets one ``(lo,
+    hi)`` per key as ``dense_range`` and ``capacity`` = G, the product of
+    the spans, where G is at most :data:`SMALL_GROUP_DOMAIN`, or for a
+    single key where its span is at most 4× its distinct values and at
+    most a million: the executor then segments by direct address — no
+    sort, no gather.  A single key past that gets its distinct count as
+    ``capacity`` for the sort path.  A join whose build key qualifies
     (:func:`_dense_join_range`) gets the range as ``dense_range`` and is
     probed by direct address — no sort, no search; a join that no longer
     qualifies loses it.  This is what a cost-based optimizer gets from
@@ -1214,27 +1274,24 @@ def annotate_group_stats(plan: R.RelNode, catalog=None):
                 return None
             changed[0] = True
             return R.Join(node.left, node.right, node.on, node.kind, want)
-        if (
-            not isinstance(node, R.GroupAgg)
-            or len(node.keys) != 1
-            or node.dense_range is not None
-        ):
+        if (not isinstance(node, R.GroupAgg) or not node.keys
+                or node.dense_range is not None):
             return None
-        st = _source_stats(node.child, node.keys[0], catalog)
-        if st is None:
+        stats = _key_stats(node, catalog)
+        if stats is None:
             return None
-        distinct, lo, hi = st
-        span = hi - lo + 1
-        if span <= 0 or span > 4 * distinct or span > 1_000_000:
-            cap = node.capacity or distinct
-            if node.capacity is None:
-                changed[0] = True
-                return R.GroupAgg(node.child, node.keys, dict(node.aggs),
-                                  distinct, None)
-            return None
-        changed[0] = True
-        return R.GroupAgg(node.child, node.keys, dict(node.aggs),
-                          node.capacity or span, (lo, hi))
+        G = math.prod(hi - lo + 1 for _, lo, hi in stats)
+        distinct = stats[0][0]
+        if 0 < G <= SMALL_GROUP_DOMAIN or (
+                len(stats) == 1 and 0 < G <= min(4 * distinct, 1_000_000)):
+            changed[0] = True
+            return R.GroupAgg(node.child, node.keys, dict(node.aggs), G,
+                              [(lo, hi) for _, lo, hi in stats])
+        if len(stats) == 1 and node.capacity is None:
+            changed[0] = True
+            return R.GroupAgg(node.child, node.keys, dict(node.aggs),
+                              distinct, None)
+        return None
 
     return R.transform_plan(plan, rule), changed[0]
 
@@ -1379,7 +1436,8 @@ def explain(plan: R.RelNode, indent: int = 0) -> str:
         out.append(explain(n.left, indent + 1))
         out.append(explain(n.right, indent + 1))
     elif isinstance(n, R.GroupAgg):
-        out.append(f"{pad}GroupAgg keys={n.keys} aggs={list(n.aggs)}")
+        dense = "" if n.dense_range is None else f" dense={list(n.dense_range)}"
+        out.append(f"{pad}GroupAgg keys={n.keys} aggs={list(n.aggs)}{dense}")
         out.append(explain(n.child, indent + 1))
     elif isinstance(n, R.Sort):
         out.append(f"{pad}Sort {n.keys} limit={n.limit}")

@@ -202,7 +202,13 @@ class GroupAgg(RelNode):
     """Grouped aggregation.  keys == [] is a full-table aggregate (1 row).
 
     ``capacity``: static upper bound on group count for jit paths; the
-    eager executor computes exact groups host-side when unset."""
+    eager executor computes exact groups host-side when unset.
+
+    ``dense_range`` (the optimizer's stats pass): one ``(lo, hi)`` per key,
+    where catalog stats bound every key's values and no key can be NULL.
+    The executor then segments by direct address — a group's slot is the
+    mixed-radix code of its keys — and returns one slot per code, sorting
+    nothing.  A bare ``(lo, hi)`` pair is the range of a single key."""
 
     def __init__(
         self,
@@ -210,7 +216,7 @@ class GroupAgg(RelNode):
         keys: Sequence[str],
         aggs: dict[str, AggSpec | tuple],
         capacity: int | None = None,
-        dense_range: tuple[int, int] | None = None,
+        dense_range: Sequence[tuple[int, int]] | tuple[int, int] | None = None,
     ):
         super().__init__()
         self.child = child
@@ -222,9 +228,11 @@ class GroupAgg(RelNode):
                 spec = AggSpec(fn, None if expr is None else S.wrap(expr))
             self.aggs[name] = spec
         self.capacity = capacity
-        # stats-derived: key values densely cover [lo, hi] -> the executor
-        # uses direct gid = key - lo segmenting (no sort)
-        self.dense_range = dense_range
+        if dense_range is not None and not isinstance(dense_range[0],
+                                                      (tuple, list)):
+            dense_range = (dense_range,)
+        self.dense_range = (None if dense_range is None else
+                            tuple((int(lo), int(hi)) for lo, hi in dense_range))
 
     def children(self):
         return [self.child]

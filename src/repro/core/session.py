@@ -762,13 +762,14 @@ class Session:
         # call that compiles holds the compile in its args_s (AOT or store
         # load) or dispatch_s (jit); repro.telemetry counts compiles apart.
         # Per plan that prepare builds, the GroupAggs the optimizer
-        # collapsed on pinned keys and the joins it gave a dense key range.
+        # collapsed on pinned keys, the joins it gave a dense key range and
+        # the GroupAggs it gave key ranges (direct address).
         # Per batched or fused program run, its placement: programs run
         # over a mesh and the calls they answered, and the padding rows its
         # bucket added (power-of-two and mesh)
         self.timing_stats = {
             "tables": 0, "catalog_s": 0.0, "pinned_groupaggs": 0,
-            "dense_joins": 0,
+            "dense_joins": 0, "dense_groupaggs": 0,
             "executions": 0, "args_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0,
             "materializations": 0, "materialize_s": 0.0,
             "sharded_waves": 0, "sharded_calls": 0, "pad_calls": 0,
@@ -1086,7 +1087,8 @@ class Session:
                 plan, self.catalog, required=set(wanted) if wanted else None
             )
             self._timed(pinned_groupaggs=O.pinned_groupaggs(plan),
-                        dense_joins=O.dense_joins(plan))
+                        dense_joins=O.dense_joins(plan),
+                        dense_groupaggs=O.dense_groupaggs(plan))
         if wanted is not None:
             try:
                 have = R.output_columns(plan, self.catalog)

@@ -167,6 +167,8 @@ class Table:
         # per-column statistics (n_distinct, min, max) — populated by
         # compute_stats(); drives capacity hints in the query optimizer
         self.stats: dict[str, tuple[int, int, int]] = {}
+        # names of the columns that hold a NULL, by compute_stats()
+        self.nullable: set[str] = set()
         if self.columns:
             n = {int(c.data.shape[0]) for c in self.columns.values()}
             if len(n) != 1:
@@ -174,7 +176,12 @@ class Table:
 
     def compute_stats(self) -> "Table":
         """Host-side column statistics for integer/dictionary columns
-        (the costing input the paper notes UDFs used to hide, §2.3)."""
+        (the costing input the paper notes UDFs used to hide, §2.3), and
+        which columns hold a NULL (their stats bound the values, not the
+        NULLs)."""
+        self.nullable = {name for name, c in self.columns.items()
+                         if c.valid is not None
+                         and not bool(np.asarray(c.valid).all())}
         for name, c in self.columns.items():
             if c.dictionary is not None:
                 self.stats[name] = (len(c.dictionary), 0, len(c.dictionary) - 1)

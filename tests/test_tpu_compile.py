@@ -106,3 +106,30 @@ def test_q12_plan_compiles_for_v5e_without_a_search_loop(one_chip,
         db._catalog_args())
     hlo = jax.jit(entry.raw).lower(args, {}).compile().as_text()
     assert " while(" not in hlo
+
+
+def test_q1_plan_compiles_for_v5e_without_a_sort_or_gather(one_chip,
+                                                          no_compile_cache):
+    """Q1 groups on two dictionary keys (3 × 2 codes): its GroupAgg takes
+    the keys' ranges from the catalog and reduces each aggregate per slot,
+    so the compiled program sorts, gathers and scatters nothing, and
+    keeps no row-sized temporary (each slot's reduction fuses)."""
+    from benchmarks.tpch_udfs import q1_udf, register_udfs
+    from repro.core import FROID, Session
+    from repro.data.tpch import generate_tpch
+
+    db = Session()
+    generate_tpch(db, sf=0.1)
+    register_udfs(db)
+    stmt = db.prepare(q1_udf(), FROID)
+    assert db.timing_stats["dense_groupaggs"] == 1, stmt.explain()
+    entry, _, _ = db._executable(stmt.node, stmt._query_fp, stmt.policy, None)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        db._catalog_args())
+    compiled = jax.jit(entry.raw).lower(args, {}).compile()
+    hlo = compiled.as_text()
+    for op in (" sort(", " gather(", " scatter("):
+        assert op not in hlo, op
+    rows = db.catalog["lineitem"].num_rows
+    assert compiled.memory_analysis().temp_size_in_bytes < rows
